@@ -255,10 +255,6 @@ def jax_speedup() -> List[Row]:
     leg runs the same warmup inside the program — so each leg is charged
     the identical physics.  Compile time is excluded (the program caches
     per workload plan / fleet shape, which is how sweeps use it)."""
-    from repro.core.jax_engine import HAS_JAX
-    if not HAS_JAX:
-        return [("cluster_jax_speedup", 0.0,
-                 "nodes=0;skipped=jax_unavailable")]
     from repro.core.jax_engine import (build_fleet_arrays, fleet_scan_spec,
                                       run_fleet_scan)
     n_nodes = 256

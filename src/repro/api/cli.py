@@ -499,6 +499,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     try:
         return args.fn(args)
     except SystemExit as e:                      # _load_scenario usage errors

@@ -11,9 +11,9 @@ Execution compiles the whole population into as few device programs as
 possible: every sample whose *static* shape (fleet size, topology, workload
 plan, iteration count) matches runs inside one batched
 :func:`repro.core.jax_engine.run_fleet_scan` — a single ``vmap``-ed XLA
-program over the sample axis.  Without JAX the sweep falls back to
-per-sample ``ClusterSim`` stepping (same physics, numpy speed).  Both paths
-drop any closed-loop manager: sweeps measure the *open-loop* fleet
+program over the sample axis.  Populations whose shapes differ fall back
+to per-sample ``ClusterSim`` stepping (same physics, numpy speed).  Both
+paths drop any closed-loop manager: sweeps measure the *open-loop* fleet
 dynamics, so the distribution reflects thermal imbalance rather than the
 mitigation policy.
 
@@ -264,10 +264,8 @@ def _run_batch_jax(variants: List[Scenario],
                    iterations: int) -> Optional[List[Dict[str, float]]]:
     """All samples whose static shape matches, as one vmapped scan program;
     None when shapes diverge (caller falls back to per-sample runs)."""
-    from repro.core.jax_engine import (HAS_JAX, build_fleet_arrays,
-                                       fleet_scan_spec, run_fleet_scan)
-    if not HAS_JAX:
-        return None
+    from repro.core.jax_engine import (build_fleet_arrays, fleet_scan_spec,
+                                       run_fleet_scan)
     specs, arrays = [], []
     for sc, seed, nseed in zip(variants, seeds, noise_seeds):
         wl = sc.workload.build()

@@ -27,10 +27,10 @@ Two programs live here, both jitted end-to-end:
     passed in as arrays and reproduces ClusterSim's numpy draws exactly
     (see :func:`build_fleet_arrays`).
 
-Everything computes in float64 (``jax.experimental.enable_x64`` is entered
-around tracing and execution; the global JAX config is left untouched so
-the float32 Pallas training substrate is unaffected).  CPU-backend JAX is
-fully supported — no GPU is required, in CI or anywhere else.
+Everything computes in float64 (``jax.enable_x64(True)`` is entered around
+tracing and execution; the global JAX config is left untouched so the
+float32 Pallas training substrate is unaffected).  The same programs run
+on the CPU backend (tests, CI) and on a TPU, where float64 is emulated.
 """
 from __future__ import annotations
 
@@ -38,28 +38,13 @@ import functools
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:                                    # the repo's jax_pallas toolchain
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    import repro._jax_compat            # noqa: F401  (version knobs)
-    HAS_JAX = True
-except Exception:                       # pragma: no cover - gated container
-    HAS_JAX = False
-
-__all__ = ["HAS_JAX", "WindowPlan", "window_plan", "jax_iteration",
+__all__ = ["WindowPlan", "window_plan", "jax_iteration",
            "FleetScanSpec", "fleet_scan_spec", "build_fleet_arrays",
            "run_fleet_scan"]
-
-
-def _require_jax() -> None:
-    if not HAS_JAX:
-        raise RuntimeError(
-            "engine='jax' requires the jax package, which this environment "
-            "does not provide — use engine='vector' (numpy) instead")
 
 
 # --------------------------------------------------------------------------- #
@@ -350,7 +335,6 @@ def jax_iteration(sims: Sequence, freqs: Sequence[np.ndarray],
     match the vector engine's within float tolerance (XLA may fuse
     multiply-adds, so bitwise equality is not guaranteed).
     """
-    _require_jax()
     wl = sims[0].wl
     A = sims[0].arrays
     cfg = sims[0].cfg
@@ -374,7 +358,7 @@ def jax_iteration(sims: Sequence, freqs: Sequence[np.ndarray],
 
     fn = _compiled_iteration(plan, float(cfg.kappa_comp),
                              float(cfg.kappa_mem))
-    with enable_x64():
+    with jax.enable_x64(True):
         out = fn(jnp.asarray(rate_f), jnp.asarray(rm),
                  jnp.asarray(work_f), jnp.asarray(work_b),
                  jnp.asarray(dur_comm))
@@ -681,9 +665,8 @@ def run_fleet_scan(spec: FleetScanSpec,
     per-iteration summary scalars) and the final thermal ``temp``/``freq``
     state, as numpy arrays.
     """
-    _require_jax()
     batched = arrays["r_th"].ndim == 3
     fn = _compiled_scan(spec, batched)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = fn({k: jnp.asarray(v) for k, v in arrays.items()})
     return {k: np.asarray(v) for k, v in out.items()}

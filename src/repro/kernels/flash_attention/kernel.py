@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -104,7 +102,7 @@ def _pad_to(x, axis, m):
                                              "block_k", "interpret"))
 def flash_attention_fwd(q, k, v, mask=None, *, causal: bool = True,
                         window: int = 0, block_q: int = 128,
-                        block_k: int = 512, interpret: bool = True):
+                        block_k: int = 512, interpret: bool):
     """q: (BH, Sq, D); k/v: (BH, Sk, D); mask: optional (Sq, Sk) bool."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -124,7 +122,7 @@ def flash_attention_fwd(q, k, v, mask=None, *, causal: bool = True,
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
     ospec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    params = CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     if mask is not None:

@@ -3,18 +3,19 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import flash_attention_ref
-
-INTERPRET = True  # CPU container: interpret mode; False on real TPU
 
 
 def flash_attention(q, k, v, mask=None, *, causal=None, window: int = 0):
     """q: (B, Sq, H, D), k/v: (B, Sk, kvH, D) -> (B, Sq, H, D).
 
     mask: None or broadcastable bool whose last two dims are (Sq, Sk).
-    Sq == 1 (decode) falls back to the jnp oracle — a single-token matvec
-    doesn't benefit from a blocked kernel.
+    Two cases run the jnp oracle instead of the kernel, on every backend:
+    Sq == 1 (decode), and masks that differ per batch or head.  The
+    trainer reaches neither: it uses the XLA ``chunked`` attention
+    (``repro.models.attention``), not this wrapper.
     """
     B, Sq, H, D = q.shape
     Sk, kvH = k.shape[1], k.shape[2]
@@ -45,5 +46,5 @@ def flash_attention(q, k, v, mask=None, *, causal=None, window: int = 0):
         out = flash_attention_fwd(
             qf, kf, vf, mask2d,
             causal=bool(causal) if causal is not None else False,
-            window=window, interpret=INTERPRET)
+            window=window, interpret=interpret_mode())
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

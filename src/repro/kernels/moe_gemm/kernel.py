@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _mm_kernel(x_ref, w_ref, o_ref, acc, *, n_d):
     di = pl.program_id(3)
@@ -45,7 +43,7 @@ def _pad_dim(x, axis, m):
 @functools.partial(jax.jit, static_argnames=("block_c", "block_h", "block_d",
                                               "interpret"))
 def moe_gemm_fwd(x, w, *, block_c: int = 128, block_h: int = 128,
-                 block_d: int = 512, interpret: bool = True):
+                 block_d: int = 512, interpret: bool):
     """x: (E, C, d), w: (E, d, h) -> (E, C, h)."""
     E, C, d = x.shape
     h = w.shape[2]
@@ -70,7 +68,7 @@ def moe_gemm_fwd(x, w, *, block_c: int = 128, block_h: int = 128,
                                lambda e, i, j, kk: (e, i, j)),
         scratch_shapes=[pltpu.VMEM((block_c, block_h), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((E, Cp, hp), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

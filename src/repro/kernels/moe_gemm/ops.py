@@ -1,11 +1,10 @@
 """jit'd wrapper for the grouped-GEMM kernel."""
 from __future__ import annotations
 
+from repro.kernels import interpret_mode
 from repro.kernels.moe_gemm.kernel import moe_gemm_fwd
-
-INTERPRET = True
 
 
 def moe_gemm(x, w):
     """x: (E, C, d), w: (E, d, h) -> (E, C, h)."""
-    return moe_gemm_fwd(x, w, interpret=INTERPRET)
+    return moe_gemm_fwd(x, w, interpret=interpret_mode())

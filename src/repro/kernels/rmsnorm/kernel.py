@@ -32,7 +32,7 @@ def _rms_res_kernel(x_ref, r_ref, w_ref, o_ref, res_ref, *, eps):
 @functools.partial(jax.jit,
                    static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm_fwd(x, w, residual=None, *, eps: float = 1e-5,
-                block_rows: int = 256, interpret: bool = True):
+                block_rows: int = 256, interpret: bool):
     """x: (..., d); w: (d,).  Optional fused residual add."""
     shape = x.shape
     d = shape[-1]

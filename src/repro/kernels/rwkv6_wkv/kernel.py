@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, s_scr,
                 *, L, n_chunks):
@@ -32,9 +30,14 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, s_scr,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     w = w_ref[0].astype(jnp.float32)               # log decay, < 0
-    u = u_ref[0].astype(jnp.float32)               # (1, D) block -> (D,)
+    u = u_ref[0].astype(jnp.float32)               # (1, 1, D) block -> (1, D)
 
-    cw = jnp.cumsum(w, axis=0)                     # inclusive
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # inclusive prefix sum as a triangular matmul (Mosaic has no cumsum)
+    cw = jax.lax.dot((row >= col).astype(jnp.float32), w,
+                     precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
     cwx = cw - w                                   # exclusive
     S_prev = s_scr[...]
 
@@ -44,17 +47,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, s_scr,
 
     # intra-chunk: A_ij = sum_d r_i k_j exp(cwx_i - cw_j), strictly lower
     expo = cwx[:, None, :] - cw[None, :, :]        # (L, L, D)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) \
-        > jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    pair = jnp.where(tri[..., None], jnp.exp(jnp.minimum(expo, 0.0)), 0.0)
-    A = jnp.einsum("id,jd,ijd->ij", r, k, pair)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, expo.shape, 0)
+           > jax.lax.broadcasted_iota(jnp.int32, expo.shape, 1))
+    pair = jnp.where(tri, jnp.exp(jnp.minimum(expo, 0.0)), 0.0)
+    A = jnp.sum(r[:, None, :] * k[None, :, :] * pair, axis=-1)
     diag = jnp.sum(r * u * k, axis=-1)             # u-weighted current token
     y = y + jax.lax.dot(A, v, preferred_element_type=jnp.float32) \
         + diag[:, None] * v
 
     # state update: S = diag(exp(cw_L)) S + sum_j (k_j exp(cw_L - cw_j))^T v_j
-    k_scaled = k * jnp.exp(cw[-1:] - cw)
-    s_scr[...] = S_prev * jnp.exp(cw[-1])[:, None] + jax.lax.dot(
+    cw_last = cw[L - 1:L]                          # (1, D), static slice
+    k_scaled = k * jnp.exp(cw_last - cw)
+    s_scr[...] = S_prev * jnp.exp(cw_last).T + jax.lax.dot(
         k_scaled.T, v, preferred_element_type=jnp.float32)
 
     o_ref[0] = y.astype(o_ref.dtype)
@@ -65,7 +69,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, s_scr,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_fwd(r, k, v, w_log, u, *, chunk: int = 64, interpret: bool = True):
+def wkv6_fwd(r, k, v, w_log, u, *, chunk: int = 64, interpret: bool):
     """r,k,v,w_log: (B,S,H,D); u: (H,D) -> (y (B,S,H,D), state (B,H,D,D))."""
     B, S, H, D = r.shape
     L = min(chunk, S)
@@ -85,21 +89,23 @@ def wkv6_fwd(r, k, v, w_log, u, *, chunk: int = 64, interpret: bool = True):
         valid = (jnp.arange(S + pad) < S)[None, :, None]
         wf = jnp.where(valid, wf, 0.0)
         kf = jnp.where(valid, kf, 0.0)
-    # u per (b,h) row: layout must match prep()'s (B*H) ordering
-    uf = jnp.broadcast_to(u[None], (B, H, D)).reshape(B * H, D)
+    # u per (b,h) row: layout must match prep()'s (B*H) ordering.  The
+    # singleton middle axis makes u's block (1, 1, D) equal the array in its
+    # last two dims, as the TPU's (8, 128) tiling requires of a block.
+    uf = jnp.broadcast_to(u[None], (B, H, D)).reshape(B * H, 1, D)
 
     spec_t = pl.BlockSpec((1, L, D), lambda b, c: (b, c, 0))
     out, s_out = pl.pallas_call(
         functools.partial(_wkv_kernel, L=L, n_chunks=n),
         grid=(B * H, n),
         in_specs=[spec_t, spec_t, spec_t, spec_t,
-                  pl.BlockSpec((1, D), lambda b, c: (b, 0))],
+                  pl.BlockSpec((1, 1, D), lambda b, c: (b, 0, 0))],
         out_specs=[spec_t,
                    pl.BlockSpec((1, D, D), lambda b, c: (b, 0, 0))],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct((B * H, S + pad, D), r.dtype),
                    jax.ShapeDtypeStruct((B * H, D, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf)

@@ -21,6 +21,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import get_config, get_reduced_config
     from repro.models import batch_extras, build_model
     from repro.models.common import init_params

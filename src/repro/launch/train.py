@@ -12,12 +12,11 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 
 import numpy as np
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.1-8b")
     ap.add_argument("--reduced", action="store_true",
@@ -33,16 +32,25 @@ def main(argv=None):
                     help="enable the Lit Silicon power-management hook")
     ap.add_argument("--preset", default="mi300x", choices=["mi300x", "v5e"])
     ap.add_argument("--metrics-out", default="")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build_trainer(args, model_cfg=None, mesh=None):
+    """The `Trainer` (and its hooks) that ``main`` runs for ``args``.
+
+    ``model_cfg`` defaults to the ``--arch`` config (reduced with
+    ``--reduced``); the Lit Silicon hook always simulates the full arch.
+    ``mesh`` defaults to every device on the 'data' axis.
+    """
     from repro.configs import (ParallelConfig, TrainConfig, get_config,
                                get_reduced_config)
     from repro.core.manager import ManagerConfig
     from repro.train.data import DataConfig
     from repro.train.train_loop import LitSiliconHook, Trainer, TrainerConfig
 
-    model_cfg = (get_reduced_config(args.arch) if args.reduced
-                 else get_config(args.arch))
+    if model_cfg is None:
+        model_cfg = (get_reduced_config(args.arch) if args.reduced
+                     else get_config(args.arch))
     tc = TrainerConfig(
         model=model_cfg,
         train=TrainConfig(lr=args.lr, warmup_steps=max(args.steps // 20, 1),
@@ -60,12 +68,19 @@ def main(argv=None):
             ManagerConfig(use_case=args.use_case, sampling_period=2,
                           warmup=3, window_size=2),
             preset=args.preset))
-    trainer = Trainer(tc, hooks=hooks)
+    return Trainer(tc, mesh=mesh, hooks=hooks)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+    trainer = build_trainer(args)
     log = trainer.run(args.steps)
     print(f"step {log[-1]['step']}: loss {log[-1]['loss']:.4f} "
           f"(start {log[0]['loss']:.4f})")
     if args.use_case:
-        h = hooks[0]
+        h = trainer.hooks[0]
         caps = h.backend.get_power_caps()
         print(f"lit-silicon[{args.use_case}]: converged caps = "
               f"{np.round(caps, 0).tolist()}")

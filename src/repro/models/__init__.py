@@ -1,4 +1,3 @@
-import repro._jax_compat  # noqa: F401  (sharding-invariant RNG)
 from repro.models.registry import (batch_extras, build_model, input_specs,
                                    make_batch)
 

@@ -1,4 +1,3 @@
-import repro._jax_compat  # noqa: F401  (sharding-invariant RNG)
 from repro.parallel.act import (activation_sharding, constrain,
                                 shard_residual)
 from repro.parallel.sharding import ShardingRules, replicated

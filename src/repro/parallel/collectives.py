@@ -14,15 +14,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
-
-
-def axis_size(axis_name: str) -> int:
-    """Version-compat: ``jax.lax.axis_size`` only exists in newer jax; the
-    ``psum(1, axis)`` idiom is constant-folded to the axis size everywhere."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def ring_all_gather(x, axis_name: str):
@@ -31,7 +22,7 @@ def ring_all_gather(x, axis_name: str):
     x: local shard (..., d).  Returns (axis_size, ..., d) stacked gathers in
     ring order, rotated so index 0 is rank 0's shard.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     chunks = [x]
@@ -74,8 +65,8 @@ def fsdp_ffn_prefetch(x, w_stacked, mesh: Mesh, *, fsdp_axis: str = "data"):
 def make_fsdp_prefetch_fn(mesh: Mesh, fsdp_axis: str = "data"):
     """shard_map-wrapped explicit-overlap FFN chain (for tests / A-B)."""
     fn = partial(fsdp_ffn_prefetch, mesh=mesh, fsdp_axis=fsdp_axis)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(fsdp_axis, None), P(None, fsdp_axis, None)),
         out_specs=P(fsdp_axis, None),
-        check_rep=False)
+        check_vma=False)

@@ -22,7 +22,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 _DISPATCH = "scatter"
@@ -80,9 +79,9 @@ def moe_forward_shard_map(cfg, p, x, gates, idx, mesh, batch_axes,
             out = jax.lax.psum(out, tp_axis)
         return out.reshape(Bl, Sl, d)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(x_spec, g_spec, g_spec, w_spec, w_spec,
-                             wd_spec),
-                   out_specs=x_spec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(x_spec, g_spec, g_spec, w_spec, w_spec,
+                                 wd_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(x, gates.astype(x.dtype), idx, p["wg"].astype(x.dtype),
               p["wu"].astype(x.dtype), p["wd"].astype(x.dtype))

@@ -11,7 +11,7 @@ Two equivalence tiers (docs/engines.md):
     one jitted scan with jax-PRNG noise: identical thermal lotteries and
     physics, a different noise stream — so the check is statistical
     (tail-mean fleet metrics), driven through the sweep module against its
-    own per-sample ``ClusterSim`` fallback.
+    own per-sample ``ClusterSim`` path (``_run_one_python``).
 """
 import json
 
@@ -22,10 +22,8 @@ from hypothesis import given, settings, strategies as st
 from conftest import small_workload
 from repro.core.c3sim import SimConfig
 from repro.core.cluster import ClusterConfig, ClusterSim
-from repro.core.jax_engine import HAS_JAX, window_plan
+from repro.core.jax_engine import window_plan
 from repro.core.thermal import MI300X_PRESET, ChurnEvent, ChurnModel
-
-pytestmark = pytest.mark.skipif(not HAS_JAX, reason="jax not installed")
 
 HETERO = ["mi300x", "mi300x-air", "mi300x", "v5e"]
 
@@ -111,8 +109,28 @@ def test_window_plan_caches_on_workload():
 # --------------------------------------------------------------------------- #
 # whole-run fleet scan: statistical equivalence via the sweep module
 # --------------------------------------------------------------------------- #
+def _python_samples(spec):
+    """The population `run_sweep` builds for ``spec``, stepped sample by
+    sample through plain ClusterSim (`_run_one_python`) instead of the
+    vmapped scan: same overrides, thermal seeds and healthy reference."""
+    from repro.api.registry import get_scenario
+    from repro.api.spec import _encode, with_overrides
+    from repro.api.sweep import _HEALTHY, _run_one_python, _sample_overrides
+
+    base = get_scenario(spec.scenario).replace(manager=None)
+    ref = _run_one_python(with_overrides(base, dict(_HEALTHY)), base.seed,
+                          spec.iterations)
+    rows = []
+    for label, ov, seed in _sample_overrides(spec, base):
+        row = _run_one_python(with_overrides(base, ov), seed, spec.iterations)
+        rows.append({"label": label, "overrides": _encode(ov),
+                     "thermal_seed": seed, **row,
+                     "recovery": row["throughput"] / ref["throughput"]})
+    return rows
+
+
 @pytest.mark.slow
-def test_fleet_scan_sweep_matches_python_fallback(monkeypatch):
+def test_fleet_scan_sweep_matches_python_fallback():
     """The same SweepSpec through both execution paths — one vmapped
     run_fleet_scan program vs per-sample ClusterSim stepping.  Thermal
     lotteries are shared; only the iteration-noise stream differs, so
@@ -124,12 +142,9 @@ def test_fleet_scan_sweep_matches_python_fallback(monkeypatch):
     jax_art = run_sweep(spec)
     assert jax_art["engine"] == "jax-scan"
 
-    import repro.core.jax_engine as je
-    monkeypatch.setattr(je, "HAS_JAX", False)
-    py_art = run_sweep(spec)
-    assert py_art["engine"] == "python"
-
-    for a, b in zip(jax_art["samples"], py_art["samples"]):
+    py_samples = _python_samples(spec)
+    assert len(py_samples) == len(jax_art["samples"])
+    for a, b in zip(jax_art["samples"], py_samples):
         assert a["label"] == b["label"]
         assert a["thermal_seed"] == b["thermal_seed"]
         for key in ("t_fleet_s", "throughput", "fleet_power_w"):
@@ -138,9 +153,9 @@ def test_fleet_scan_sweep_matches_python_fallback(monkeypatch):
 
 
 @pytest.mark.slow
-def test_fleet_scan_handles_churn_and_hetero(monkeypatch):
+def test_fleet_scan_handles_churn_and_hetero():
     """Churn event tables and per-node preset constants ride the scan as
-    data: the churn scenario's population matches the python fallback."""
+    data: the churn scenario's population matches per-sample ClusterSim."""
     from repro.api.sweep import SweepSpec, run_sweep
 
     spec = SweepSpec(scenario="cluster/churn", samples=2, seed=1,
@@ -149,10 +164,9 @@ def test_fleet_scan_handles_churn_and_hetero(monkeypatch):
     jax_art = run_sweep(spec)
     assert jax_art["engine"] == "jax-scan"
 
-    import repro.core.jax_engine as je
-    monkeypatch.setattr(je, "HAS_JAX", False)
-    py_art = run_sweep(spec)
-    for a, b in zip(jax_art["samples"], py_art["samples"]):
+    py_samples = _python_samples(spec)
+    assert len(py_samples) == len(jax_art["samples"])
+    for a, b in zip(jax_art["samples"], py_samples):
         assert a["overrides"] == b["overrides"]
         assert a["t_fleet_s"] == pytest.approx(b["t_fleet_s"], rel=1e-2)
 

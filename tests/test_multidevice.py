@@ -31,13 +31,12 @@ def test_ring_all_gather_matches_allgather():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel.collectives import ring_all_gather
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
         x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
-        f = shard_map(lambda s: ring_all_gather(s, "data"),
-                      mesh=mesh, in_specs=P("data", None),
-                      out_specs=P("data", None, None), check_rep=False)
+        f = jax.shard_map(lambda s: ring_all_gather(s, "data"),
+                          mesh=mesh, in_specs=P("data", None),
+                          out_specs=P("data", None, None), check_vma=False)
         out = f(x)   # (8*8//8? -> (8, 1, 4) stacked chunks per shard
         out = np.asarray(out).reshape(8, 8, 1, 4)
         for r in range(8):
@@ -50,13 +49,12 @@ def test_compressed_psum_close_to_exact():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel.compression import compressed_psum
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
-        f = shard_map(lambda s: compressed_psum(s, "data"), mesh=mesh,
-                      in_specs=P("data", None), out_specs=P("data", None),
-                      check_rep=False)
+        f = jax.shard_map(lambda s: compressed_psum(s, "data"), mesh=mesh,
+                          in_specs=P("data", None), out_specs=P("data", None),
+                          check_vma=False)
         approx = np.asarray(f(x))[0]
         exact = np.asarray(x.sum(0))
         scale = np.abs(np.asarray(x)).max() / 127.0

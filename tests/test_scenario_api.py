@@ -323,13 +323,7 @@ def _wl8():
     return fsdp_llm_iteration(cfg, batch=2, seq=4096, n_shards=8)
 
 
-@pytest.mark.parametrize("engine", [
-    "event", "batched", "vector",
-    pytest.param("jax", marks=pytest.mark.skipif(
-        not __import__("repro.core.jax_engine",
-                       fromlist=["HAS_JAX"]).HAS_JAX,
-        reason="jax not installed")),
-])
+@pytest.mark.parametrize("engine", ["event", "batched", "vector", "jax"])
 def test_cluster_dp_scenario_matches_hand_wired_bit_for_bit(engine):
     """`run_scenario` on ``cluster/dp`` == the pre-API ClusterSim +
     FleetPowerManager composition, float for float, per engine."""
