@@ -41,6 +41,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.spec import (Scenario, _decode_value, _encode,
                             with_overrides)
@@ -266,24 +267,36 @@ def _run_batch_jax(variants: List[Scenario],
     None when shapes diverge (caller falls back to per-sample runs)."""
     from repro.core.jax_engine import (build_fleet_arrays, fleet_scan_spec,
                                        run_fleet_scan)
-    specs, arrays = [], []
-    for sc, seed, nseed in zip(variants, seeds, noise_seeds):
-        wl = sc.workload.build()
-        if sc.fleet.topology not in ("dp", "pp", "tp"):
-            return None
-        specs.append(fleet_scan_spec(wl, sc.sim, sc.fleet, iterations,
-                                     collect="summary",
-                                     devices_per_node=sc.node.devices))
-        arrays.append(build_fleet_arrays(
+    with TraceAnnotation("sweep.workload"):
+        workloads, specs = [], []
+        for i, sc in enumerate(variants):
+            # rows whose workload specs agree share one built workload:
+            # building is pure, and one workload held per row until the
+            # arrays stage grows the heap the garbage collector walks
+            if i and sc.workload == variants[i - 1].workload:
+                wl = workloads[-1]
+            else:
+                wl = sc.workload.build()
+            if sc.fleet.topology not in ("dp", "pp", "tp"):
+                return None
+            workloads.append(wl)
+            specs.append(fleet_scan_spec(wl, sc.sim, sc.fleet, iterations,
+                                         collect="summary",
+                                         devices_per_node=sc.node.devices))
+        if len(set(specs)) != 1:
+            return None                   # mixed shapes: no single program
+    with TraceAnnotation("sweep.fleet_arrays"):
+        arrays = [build_fleet_arrays(
             wl, sc.node.build_preset(), sc.sim, sc.fleet, sc.node.caps_w,
-            seed, devices_per_node=sc.node.devices, rng_seed=nseed))
-    if len(set(specs)) != 1:
-        return None                       # mixed shapes: no single program
-    stacked = {k: np.stack([a[k] for a in arrays]) for k in arrays[0]}
+            seed, devices_per_node=sc.node.devices, rng_seed=nseed)
+            for sc, wl, seed, nseed in zip(variants, workloads, seeds,
+                                           noise_seeds)]
+        stacked = {k: np.stack([a[k] for a in arrays]) for k in arrays[0]}
     out = run_fleet_scan(specs[0], stacked)
-    return [_series_metrics(out["t_fleet"][i], out["lead_max"][i],
-                            out["fleet_power"][i])
-            for i in range(len(variants))]
+    with TraceAnnotation("sweep.collect"):
+        return [_series_metrics(out["t_fleet"][i], out["lead_max"][i],
+                                out["fleet_power"][i])
+                for i in range(len(variants))]
 
 
 def _run_one_python(sc: Scenario, seed: int,
@@ -320,53 +333,61 @@ def run_sweep(spec: SweepSpec) -> dict:
     Raises ``ValueError`` for non-fleet scenarios — sweeps are fleet
     populations by definition (node-level studies sweep via the CLI
     ``--grid`` rows instead).
+
+    Under a profiler session the call is one ``run_sweep`` span holding a
+    span per stage of its host work (docs/sweeps.md, "Tracing a sweep").
     """
-    from repro.api.registry import get_scenario
-    spec.validate()
-    base = get_scenario(spec.scenario)
-    if base.fleet is None:
-        raise ValueError(f"sweep requires a fleet scenario; "
-                         f"{spec.scenario!r} is node-scoped")
-    base = base.replace(manager=None)       # open-loop population
-    iters = (base.iterations if spec.iterations is None
-             else int(spec.iterations))
-    mode = "grid" if spec.grid is not None else "mc"
+    with TraceAnnotation("run_sweep"):
+        with TraceAnnotation("sweep.sample"):
+            from repro.api.registry import get_scenario
+            spec.validate()
+            base = get_scenario(spec.scenario)
+            if base.fleet is None:
+                raise ValueError(f"sweep requires a fleet scenario; "
+                                 f"{spec.scenario!r} is node-scoped")
+            base = base.replace(manager=None)       # open-loop population
+            iters = (base.iterations if spec.iterations is None
+                     else int(spec.iterations))
+            mode = "grid" if spec.grid is not None else "mc"
 
-    cells = _sample_overrides(spec, base)
-    # the healthy reference rides the same batch as its final row
-    variants = [with_overrides(base, ov) for _, ov, _ in cells]
-    variants.append(with_overrides(base, dict(_HEALTHY)))
-    seeds = [s for _, _, s in cells] + [base.seed]
-    noise_seeds = [spec.seed * 1_000_003 + k for k in range(len(cells))]
-    # the reference's noise stream sits far past any realistic sample index
-    noise_seeds.append(spec.seed * 1_000_003 + 999_999_937)
+            cells = _sample_overrides(spec, base)
+            # the healthy reference rides the same batch as its final row
+            variants = [with_overrides(base, ov) for _, ov, _ in cells]
+            variants.append(with_overrides(base, dict(_HEALTHY)))
+            seeds = [s for _, _, s in cells] + [base.seed]
+            noise_seeds = [spec.seed * 1_000_003 + k
+                           for k in range(len(cells))]
+            # the reference's noise stream sits far past any realistic
+            # sample index
+            noise_seeds.append(spec.seed * 1_000_003 + 999_999_937)
 
-    rows = _run_batch_jax(variants, seeds, noise_seeds, iters)
-    engine = "jax-scan"
-    if rows is None:
-        engine = "python"
-        rows = [_run_one_python(sc, seed, iters)
-                for sc, seed in zip(variants, seeds)]
-    ref = rows.pop()
-    ref_tput = max(ref["throughput"], 1e-12)
+        rows = _run_batch_jax(variants, seeds, noise_seeds, iters)
+        engine = "jax-scan"
+        if rows is None:
+            engine = "python"
+            rows = [_run_one_python(sc, seed, iters)
+                    for sc, seed in zip(variants, seeds)]
 
-    samples = []
-    for (label, ov, seed), row in zip(cells, rows):
-        samples.append({
-            "sample": len(samples), "label": label,
-            "overrides": _encode(ov), "thermal_seed": seed,
-            **row, "recovery": row["throughput"] / ref_tput,
-        })
-    names = ("t_fleet_s", "throughput", "lead_max_s", "fleet_power_w",
-             "recovery")
-    summary = summarize({n: [s[n] for s in samples] for n in names})
-    return {
-        "format": SWEEP_FORMAT, "version": SWEEP_VERSION,
-        "scenario": spec.scenario, "mode": mode, "engine": engine,
-        "seed": spec.seed, "iterations": iters,
-        "n_samples": len(samples),
-        "sweep_spec": spec.to_dict(),
-        "reference": ref,
-        "samples": samples,
-        "summary": summary,
-    }
+        with TraceAnnotation("sweep.collect"):
+            ref = rows.pop()
+            ref_tput = max(ref["throughput"], 1e-12)
+            samples = []
+            for (label, ov, seed), row in zip(cells, rows):
+                samples.append({
+                    "sample": len(samples), "label": label,
+                    "overrides": _encode(ov), "thermal_seed": seed,
+                    **row, "recovery": row["throughput"] / ref_tput,
+                })
+            names = ("t_fleet_s", "throughput", "lead_max_s",
+                     "fleet_power_w", "recovery")
+            summary = summarize({n: [s[n] for s in samples] for n in names})
+            return {
+                "format": SWEEP_FORMAT, "version": SWEEP_VERSION,
+                "scenario": spec.scenario, "mode": mode, "engine": engine,
+                "seed": spec.seed, "iterations": iters,
+                "n_samples": len(samples),
+                "sweep_spec": spec.to_dict(),
+                "reference": ref,
+                "samples": samples,
+                "summary": summary,
+            }

@@ -664,9 +664,18 @@ def run_fleet_scan(spec: FleetScanSpec,
     either full (T, N) ``t_local``/``lead``/``node_power`` series or
     per-iteration summary scalars) and the final thermal ``temp``/``freq``
     state, as numpy arrays.
+
+    Three profiler spans name its host stages: ``fleet_scan.put`` (inputs
+    to the device), ``fleet_scan.call`` (dispatch, and compile on a cache
+    miss) and ``fleet_scan.fetch`` (wait for the device, outputs to the
+    host).
     """
     batched = arrays["r_th"].ndim == 3
     fn = _compiled_scan(spec, batched)
     with jax.enable_x64(True):
-        out = fn({k: jnp.asarray(v) for k, v in arrays.items()})
-    return {k: np.asarray(v) for k, v in out.items()}
+        with jax.profiler.TraceAnnotation("fleet_scan.put"):
+            args = {k: jnp.asarray(v) for k, v in arrays.items()}
+        with jax.profiler.TraceAnnotation("fleet_scan.call"):
+            out = fn(args)
+    with jax.profiler.TraceAnnotation("fleet_scan.fetch"):
+        return {k: np.asarray(v) for k, v in out.items()}

@@ -171,6 +171,27 @@ def test_fleet_scan_handles_churn_and_hetero():
         assert a["t_fleet_s"] == pytest.approx(b["t_fleet_s"], rel=1e-2)
 
 
+def test_sweep_rows_keep_their_own_workloads():
+    """Rows share one built workload only where their workload specs
+    agree: in a population whose rows switch between two workload specs,
+    each row gets what a batch of that row alone gets."""
+    from repro.api.registry import get_scenario
+    from repro.api.spec import with_overrides
+    from repro.api.sweep import _run_batch_jax
+
+    base = get_scenario("cluster/dp").replace(manager=None)
+    variants = [with_overrides(base, {"workload.batch": b,
+                                      "fleet.straggler_boost": 1.1 + 0.1 * k})
+                for k, b in enumerate((1, 1, 2, 1))]
+    seeds, noise = [5, 6, 7, 8], [11, 12, 13, 14]
+    rows = _run_batch_jax(variants, seeds, noise, 3)
+    assert rows is not None
+    assert rows[1]["t_fleet_s"] != rows[2]["t_fleet_s"]
+    for i, row in enumerate(rows):
+        (alone,) = _run_batch_jax([variants[i]], [seeds[i]], [noise[i]], 3)
+        assert row == pytest.approx(alone, rel=1e-12), i
+
+
 def test_sweep_artifact_schema(tmp_path):
     """The artifact validates against the docs/sweeps.md schema and is
     valid strict JSON (no NaN/Inf literals)."""
