@@ -29,8 +29,18 @@ Two programs live here, both jitted end-to-end:
 
 Everything computes in float64 (``jax.enable_x64(True)`` is entered around
 tracing and execution; the global JAX config is left untouched so the
-float32 Pallas training substrate is unaffected).  The same programs run
-on the CPU backend (tests, CI) and on a TPU, where float64 is emulated.
+float32 Pallas training substrate is unaffected) with one exception: the
+fleet scan's lognormal noise factors (per-kernel, per-collective and TP
+jitter) evaluate the inverse error function and the ``exp`` after it in
+float32 (:func:`_normal_f32`).  A TPU has no native float64; emulated,
+that ``erf_inv`` over every (sample, node, device, kernel) element was the
+largest cost of a sweep, and the factors need no more than float32.  Their
+uniforms are still the float64 threefry draws of
+``jax.random.normal(key, shape, float64)``, so the stream is the same and
+only the rounding differs: ≤ ~3e-7 of max(1, |z|) on the CPU, ≤ ~2.4e-5 on
+a TPU v5e, whose float32 ``log`` and ``exp`` are looser.  The same
+programs run on the CPU backend (tests, CI) and on a TPU, where float64 is
+emulated.
 """
 from __future__ import annotations
 
@@ -510,6 +520,59 @@ def build_fleet_arrays(workload, preset, sim_cfg, cluster_cfg,
     return arrays
 
 
+# erf_inv(x) = p(w)·x with w = -log(1 - x²), per branch of w: Giles'
+# single-precision polynomials (XLA's float32 ErfInv) in w - 2.5 below 5
+# and in sqrt(w) - 3 below 16, and past 16, where float32 inputs never
+# reach but 1 - |u| of a float64 uniform does (down to 2^-53, w ≤ 36.05),
+# a fit of erf_inv(x)/x in sqrt(w) - 5 (relative error 4.3e-9 against
+# mpmath on sqrt(w) in [4, 6.01]).  Rows: Horner order, highest first.
+_ERFINV_F32 = np.array([
+    (2.81022636e-08, 3.43273939e-07, -3.5233877e-06, -4.39150654e-06,
+     0.00021858087, -0.00125372503, -0.00417768164, 0.246640727,
+     1.50140941),
+    (-0.000200214257, 0.000100950558, 0.00134934322, -0.00367342844,
+     0.00573950773, -0.0076224613, 0.00943887047, 1.00167406, 2.83297682),
+    (0.0, 0.0, 5.28401461e-06, -2.16353146e-05, 7.53193890e-05,
+     -0.000214010739, -0.000138525720, 1.01030028, 4.84990645),
+], np.float32)
+
+
+def _sqrt2_erf_inv_f32(u):
+    """``√2·erf_inv(u)`` in float32 for float64 ``u`` in (-1, 1).
+
+    ``t = 1 - |u|`` is formed in float64 before the cast, so a ``u`` within
+    2^-25 of ±1 (which would round to ±1 in float32, and give ±inf) keeps
+    its distance from the pole; ``1 - u² = t·(2 - t)``.  Odd in ``u``.
+    """
+    f32 = np.float32
+    t = (1.0 - jnp.abs(u)).astype(jnp.float32)
+    w = -jnp.log(t * (f32(2.0) - t))
+    centre, tail = w < f32(5.0), w < f32(16.0)
+    x = jnp.where(centre, w - f32(2.5),
+                  jnp.sqrt(w) - jnp.where(tail, f32(3.0), f32(5.0)))
+    coef = [jnp.where(centre, c_centre, jnp.where(tail, c_tail, c_far))
+            for c_centre, c_tail, c_far in _ERFINV_F32.T]
+    p = coef[0]
+    for c in coef[1:]:
+        p = c + p * x
+    return f32(np.sqrt(2.0)) * p * u.astype(jnp.float32)
+
+
+def _normal_f32(key, shape):
+    """The draw of ``jax.random.normal(key, shape, float64)`` in float32.
+
+    Same key, same bits, same float64 uniform on [nextafter(-1, 0), 1) as
+    ``jax.random.normal``; only the transform to a normal runs in float32
+    (see `_sqrt2_erf_inv_f32`).  Matches the float64 draw to ~3e-7 of
+    max(1, |z|) where float32 ``log`` is correctly rounded (the CPU), to
+    ~2.4e-5 on a TPU v5e, and is finite for every uniform the draw can
+    give.
+    """
+    u = jax.random.uniform(key, shape, jnp.float64,
+                           np.nextafter(-1.0, 0.0), 1.0)
+    return _sqrt2_erf_inv_f32(u)
+
+
 def _fleet_scan_core(spec: FleetScanSpec, a: Dict):
     """The pure scan program: warmup (uncoupled, TDP caps) then the main
     coupled loop, all under one trace.  ``a`` is the `build_fleet_arrays`
@@ -535,11 +598,15 @@ def _fleet_scan_core(spec: FleetScanSpec, a: Dict):
         ev = jnp.prod(jnp.where(onehot, active[:, :, None], 1.0), axis=1)
         return a["r_th"] * drift * ev
 
+    def lognormal(key, shape, sigma):
+        z = _normal_f32(key, shape)
+        return jnp.exp(sigma.astype(jnp.float32) * z).astype(jnp.float64)
+
     def draw_noise(key):
         k1, k2, k3 = jax.random.split(key, 3)
-        noise_c = jnp.exp(a["noise"] * jax.random.normal(k1, (N, G, Kc)))
+        noise_c = lognormal(k1, (N, G, Kc), a["noise"])
         base = a["cbytes"][None, :] / (a["comm_gbps"] * 1e9)
-        dur = base * jnp.exp(a["noise"] * jax.random.normal(k2, (N, Km)))
+        dur = base * lognormal(k2, (N, Km), a["noise"])
         if spec.spike:
             ks, ku = jax.random.split(k3)
             hit = jax.random.uniform(ks, (N, Km)) < a["comm_spike_p"]
@@ -570,7 +637,7 @@ def _fleet_scan_core(spec: FleetScanSpec, a: Dict):
             lead = t_fleet - t_local
         else:                           # tp
             K = spec.tp_syncs
-            w = jnp.exp(jax.random.normal(key, (N, K)) * a["tp_jitter"])
+            w = lognormal(key, (N, K), a["tp_jitter"])
             w = w / jnp.sum(w, axis=1, keepdims=True)
             seg = t_local[:, None] * w
             seg_max = jnp.max(seg, axis=0)

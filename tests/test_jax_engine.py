@@ -107,6 +107,82 @@ def test_window_plan_caches_on_workload():
 
 
 # --------------------------------------------------------------------------- #
+# the fleet scan's float32 normal draw: same stream as float64 normal
+# --------------------------------------------------------------------------- #
+def test_normal_f32_matches_float64_normal():
+    """`_normal_f32` is `jax.random.normal(key, shape, float64)` rounded
+    to float32 accuracy: the same bits, not another stream."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.jax_engine import _normal_f32
+
+    shape = (256 * 1024,)
+    with jax.enable_x64(True):
+        for seed in range(4):
+            key = jax.random.PRNGKey(seed)
+            z64 = np.asarray(jax.random.normal(key, shape, jnp.float64))
+            z32 = jax.jit(_normal_f32, static_argnums=1)(key, shape)
+            assert z32.dtype == jnp.float32
+            z32 = np.asarray(z32, np.float64)
+            np.testing.assert_array_less(
+                np.abs(z32 - z64), 1e-6 * np.maximum(1.0, np.abs(z64)))
+
+
+def test_normal_f32_is_finite_odd_and_exact_at_the_poles():
+    """Uniforms within 2^-24 of ±1 round to ±1 in float32; the transform
+    keeps 1 - |u| from float64, so it stays finite, odd and accurate out
+    to the last uniform the draw can give."""
+    import jax
+    import jax.numpy as jnp
+    from jax.scipy.special import erfinv
+    from repro.core.jax_engine import _sqrt2_erf_inv_f32
+
+    # nextafter(-1, 0) = -1 + 2^-53: the draw's lower bound
+    edges = [np.nextafter(-1.0, 0.0), 1 - 2.0 ** -24, -(1 - 2.0 ** -24),
+             1 - 2.0 ** -53, 0.0]
+    tail = list(1 - 2.0 ** -np.arange(1.0, 53.5, 0.25))   # every branch
+    with jax.enable_x64(True):
+        u = jnp.asarray(edges + tail, jnp.float64)
+        z = np.asarray(_sqrt2_erf_inv_f32(u), np.float64)
+        z_neg = np.asarray(_sqrt2_erf_inv_f32(-u), np.float64)
+        ref = np.sqrt(2.0) * np.asarray(erfinv(u))
+    assert np.all(np.isfinite(z)) and np.all(np.isfinite(z_neg))
+    np.testing.assert_array_equal(z_neg, -z)
+    np.testing.assert_array_less(np.abs(z - ref),
+                                 1e-6 * np.maximum(1.0, np.abs(ref)))
+
+
+@pytest.mark.parametrize("scenario", ["cluster/dp", "cluster/tp"])
+def test_fleet_scan_has_no_float64_erf_inv(scenario):
+    """The scan's noise (kernel, collective and TP jitter) takes no float64
+    inverse error function: on a TPU that is emulated, and it was most of
+    a sweep's device time.  Its transcendentals run in float32."""
+    import functools
+    import re
+
+    import jax
+    from repro.api.registry import get_scenario
+    from repro.core.jax_engine import (_fleet_scan_core, build_fleet_arrays,
+                                       fleet_scan_spec)
+
+    sc = get_scenario(scenario).replace(manager=None)
+    wl = sc.workload.build()
+    spec = fleet_scan_spec(wl, sc.sim, sc.fleet, 3, collect="summary",
+                           devices_per_node=sc.node.devices)
+    assert spec.n_nodes == 4
+    rows = [build_fleet_arrays(wl, sc.node.build_preset(), sc.sim, sc.fleet,
+                               sc.node.caps_w, seed, rng_seed=seed,
+                               devices_per_node=sc.node.devices)
+            for seed in (1, 2)]
+    stacked = {k: np.stack([r[k] for r in rows]) for k in rows[0]}
+    with jax.enable_x64(True):
+        text = jax.jit(jax.vmap(functools.partial(
+            _fleet_scan_core, spec))).lower(stacked).as_text()
+    assert not re.findall(r"erf_inv.*f64", text)
+    assert re.findall(r"stablehlo\.log .*f32>", text)
+
+
+# --------------------------------------------------------------------------- #
 # whole-run fleet scan: statistical equivalence via the sweep module
 # --------------------------------------------------------------------------- #
 def _python_samples(spec):
