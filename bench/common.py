@@ -81,8 +81,3 @@ def load_module(path: Path, name: str):
     spec.loader.exec_module(mod)
     return mod
 
-
-def percentile(values, q: float) -> float:
-    """The ``q``-th percentile (0-100) of ``values``, linear between ranks."""
-    import numpy as np
-    return float(np.percentile(np.asarray(values, float), q))

@@ -140,7 +140,7 @@ class Driver:
         the program's sharded layout, with a fresh optimizer state."""
         import jax
         import jax.numpy as jnp
-        from bench.reference.qwen3 import init_params, param_shapes
+        from bench.reference.qwen3 import init_params, param_shapes, seed_key
         from repro.parallel.fsdp import TrainState
         from repro.train.optimizer import AdamWState
 
@@ -152,10 +152,10 @@ class Driver:
                              f"configuration's {param_shapes(self.cfg)}")
         treedef = jax.tree_util.tree_structure(spec, is_leaf=is_spec)
         order = list(like)
-        cfg, seed = self.cfg, self.seed
+        cfg = self.cfg
 
-        def make():
-            p = init_params(cfg, seed)
+        def make(key):
+            p = init_params(cfg, key)
             params = jax.tree_util.tree_unflatten(treedef,
                                                   [p[k] for k in order])
             zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
@@ -163,21 +163,21 @@ class Driver:
                 jnp.zeros((), jnp.int32), zeros,
                 jax.tree_util.tree_map(jnp.zeros_like, params)), None)
 
-        trainer.state = jax.jit(make,
-                                out_shardings=trainer.state_shardings)()
+        trainer.state = jax.jit(make, out_shardings=trainer.state_shardings)(
+            seed_key(self.seed))
         trainer.step = 0
 
     def program_readings(self, trainer) -> dict:
         """Drive the first steps and read losses, the first clipped
         gradient and the parameters' change, per leaf."""
         import jax
-        from bench.reference.qwen3 import init_leaf, leaf_norms
+        from bench.reference.qwen3 import init_leaf, leaf_norms, seed_key
         b1 = trainer.cfg.train.beta1
-        cfg, seed = self.cfg, self.seed
+        cfg = self.cfg
         g_norms = jax.jit(lambda m: leaf_norms(
             {k: x / (1 - b1) for k, x in flat(m).items()}))
-        d_norms = jax.jit(lambda p: leaf_norms(
-            {k: x - init_leaf(cfg, seed, k, x.shape)
+        d_norms = jax.jit(lambda p, key: leaf_norms(
+            {k: x - init_leaf(cfg, key, k, x.shape)
              for k, x in flat(p).items()}))
         self.batches = []
         g1 = None
@@ -187,7 +187,8 @@ class Driver:
             if i == 0:
                 g1 = {k: float(x) for k, x in
                       g_norms(trainer.state.opt.exp_avg).items()}
-        delta = {k: float(x) for k, x in d_norms(trainer.state.params).items()}
+        delta = {k: float(x) for k, x in
+                 d_norms(trainer.state.params, seed_key(self.seed)).items()}
         losses = [m["loss"] for m in trainer.metrics_log[:CHECKED_STEPS]]
         return {"losses": losses, "g1": g1, "delta": delta}
 

@@ -1,13 +1,20 @@
-"""Model FLOP utilisation of the whole train step: the forward and
-backward FLOPs each token needs (``bench/flops.py``, from the
-configuration's shapes; recomputation not counted), times the tokens per
-second of the whole window, over chips x the bf16 peak of
-``bench/peaks.json``."""
+"""Model FLOP utilisation of the jitted train step: the forward and
+backward FLOPs a step's tokens need (``bench/flops.py``, from the
+configuration's shapes; recomputation not counted), over the device time
+of a step in ``train_step`` (``train.step_device_ms``'s programs) x chips
+x the bf16 peak of ``bench/peaks.json``.  The host's time between steps is
+not in it: that is ``train.idle_share``'s."""
 from bench.flops import train_flops_per_token
+
+PROGRAM = r"train_step"
 
 
 def read(run):
-    tokens = sum(r["tokens"] for r in run.records)
-    rate = tokens / run.window_s
+    s = run.trace.program_s(PROGRAM)
+    if s is None:
+        return None
+    step_s = s / len(run.trace.steps)
+    tokens = run.traffic["global_batch"] * run.traffic["seq_len"]
+    flops = train_flops_per_token(run.config, run.traffic) * tokens
     peak = run.peaks["bf16_flops_per_s"] * len(run.devices)
-    return 100.0 * train_flops_per_token(run.config, run.traffic) * rate / peak
+    return 100.0 * flops / (step_s * peak)

@@ -10,8 +10,8 @@ is the mean cross-entropy over labelled positions plus ``z_loss_weight``
 times the mean squared log-partition; AdamW with global-norm clipping,
 linear warm-up then cosine decay, weight decay on every parameter.
 
-Weights are made by `init_params` from a seed, for the program and for
-this reference alike: normal with the configuration's
+Weights are made by `init_params` from the seed's key (`seed_key`), for
+the program and for this reference alike: normal with the configuration's
 ``initializer_range`` for matrices and the embedding, ones for the norms.
 
 ``precision="float32"`` computes every product in float32 at the highest
@@ -50,23 +50,24 @@ def param_shapes(cfg: dict) -> Dict[str, tuple]:
 
 
 def seed_key(seed: int):
-    """A threefry key from any whole number up to 2**62."""
+    """A threefry key from any whole number up to 2**62.  Programs take it
+    as an argument, so that one compiled program serves every seed."""
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
                               (seed >> 31) & 0x7FFFFFFF)
 
 
-def init_leaf(cfg: dict, seed: int, path: str, shape: tuple):
+def init_leaf(cfg: dict, key, path: str, shape: tuple):
     if path.endswith("norm/w") or path.endswith("_norm"):
         return jnp.ones(shape, jnp.float32)
-    key = jax.random.fold_in(seed_key(seed), zlib.crc32(path.encode()) >> 1)
+    key = jax.random.fold_in(key, zlib.crc32(path.encode()) >> 1)
     return cfg["initializer_range"] * jax.random.normal(key, shape,
                                                         jnp.float32)
 
 
-def init_params(cfg: dict, seed: int) -> Dict[str, jnp.ndarray]:
-    """Flat ``path -> array``; call under ``jax.jit`` to make them on the
-    device in one program."""
-    return {p: init_leaf(cfg, seed, p, s)
+def init_params(cfg: dict, key) -> Dict[str, jnp.ndarray]:
+    """Flat ``path -> array`` from the seed's key; call under ``jax.jit``
+    to make them on the device in one program."""
+    return {p: init_leaf(cfg, key, p, s)
             for p, s in param_shapes(cfg).items()}
 
 
@@ -112,34 +113,56 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def hidden(cfg: dict, p: dict, tokens, precision: str):
-    """Final normed hidden states (B, S, d)."""
+def attend(dot, q, k, v, block: int):
+    """Causal softmax attention of q over k and v, all (B, S, H, D), for
+    ``block`` queries at a time, each block recomputed in the backward
+    pass, so that no (S, S) matrix of scores exists whole."""
+    B, S, H, D = q.shape
+    block = block if S % block == 0 else S
+    kpos = jnp.arange(S)
+
+    @jax.checkpoint
+    def one(args):
+        qb, i = args
+        s = dot("bqhd,bkhd->bhqk", qb, k) * D ** -0.5
+        seen = kpos[None, :] <= (i * block + jnp.arange(block))[:, None]
+        a = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+        return dot("bhqk,bkhd->bqhd", a, v)
+
+    qs = jnp.moveaxis(q.reshape(B, S // block, block, H, D), 1, 0)
+    o = jax.lax.map(one, (qs, jnp.arange(S // block)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, S, H * D)
+
+
+def hidden(cfg: dict, p: dict, tokens, precision: str, block: int = 512):
+    """Final normed hidden states (B, S, d).  Each layer is recomputed in
+    the backward pass, so that only its input is kept."""
     dot = _dot(precision)
     eps, hd = cfg["rms_norm_eps"], cfg["head_dim"]
     H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     B, S = tokens.shape
-    x = p["embed"][tokens]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    for layer in range(cfg["num_hidden_layers"]):
-        def w(name):
-            return p[f"g0/{name}"][layer]
-        h = rmsnorm(x, w("ln1/w"), eps)
-        q = dot("bsd,de->bse", h, w("attn/wq")).reshape(B, S, H, hd)
-        k = dot("bsd,de->bse", h, w("attn/wk")).reshape(B, S, KV, hd)
-        v = dot("bsd,de->bse", h, w("attn/wv")).reshape(B, S, KV, hd)
-        q = rope(rmsnorm(q, w("attn/q_norm"), eps), cfg["rope_theta"])
-        k = rope(rmsnorm(k, w("attn/k_norm"), eps), cfg["rope_theta"])
+
+    @jax.checkpoint
+    def layer(x, w):
+        h = rmsnorm(x, w["ln1/w"], eps)
+        q = dot("bsd,de->bse", h, w["attn/wq"]).reshape(B, S, H, hd)
+        k = dot("bsd,de->bse", h, w["attn/wk"]).reshape(B, S, KV, hd)
+        v = dot("bsd,de->bse", h, w["attn/wv"]).reshape(B, S, KV, hd)
+        q = rope(rmsnorm(q, w["attn/q_norm"], eps), cfg["rope_theta"])
+        k = rope(rmsnorm(k, w["attn/k_norm"], eps), cfg["rope_theta"])
         k = jnp.repeat(k, H // KV, axis=2)
         v = jnp.repeat(v, H // KV, axis=2)
-        s = dot("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        a = jax.nn.softmax(s, axis=-1)
-        o = dot("bhqk,bkhd->bqhd", a, v).reshape(B, S, H * hd)
-        x = x + dot("bse,ed->bsd", o, w("attn/wo"))
-        h = rmsnorm(x, w("ln2/w"), eps)
-        g = jax.nn.silu(dot("bsd,df->bsf", h, w("ffn/wg")))
-        u = dot("bsd,df->bsf", h, w("ffn/wu"))
-        x = x + dot("bsf,fd->bsd", g * u, w("ffn/wd"))
+        o = attend(dot, q, k, v, block)
+        x = x + dot("bse,ed->bsd", o, w["attn/wo"])
+        h = rmsnorm(x, w["ln2/w"], eps)
+        g = jax.nn.silu(dot("bsd,df->bsf", h, w["ffn/wg"]))
+        u = dot("bsd,df->bsf", h, w["ffn/wu"])
+        return x + dot("bsf,fd->bsd", g * u, w["ffn/wd"])
+
+    x = p["embed"][tokens]
+    for i in range(cfg["num_hidden_layers"]):
+        x = layer(x, {k[3:]: a[i] for k, a in p.items()
+                      if k.startswith("g0/")})
     return rmsnorm(x, p["final_norm/w"], eps)
 
 
@@ -183,7 +206,8 @@ def lr_at(train: dict, step):
 
 def train_step(cfg: dict, train: dict, precision: str, chunks: int,
                p: dict, m: dict, v: dict, step, tokens, labels):
-    """One AdamW step; returns (p, m, v, loss, clipped gradient)."""
+    """One AdamW step; returns (p, m, v, loss, the clipped gradient's
+    per-leaf norms)."""
     loss, g = jax.value_and_grad(
         lambda q: loss_fn(cfg, train, q, tokens, labels, precision, chunks)
     )(p)
@@ -197,7 +221,7 @@ def train_step(cfg: dict, train: dict, precision: str, chunks: int,
     v = {k: b2 * v[k] + (1 - b2) * g[k] * g[k] for k in p}
     p = {k: p[k] - lr * ((m[k] / c1) / (jnp.sqrt(v[k] / c2) + eps)
                          + train["weight_decay"] * p[k]) for k in p}
-    return p, m, v, loss, g
+    return p, m, v, loss, leaf_norms(g)
 
 
 def leaf_norms(tree: dict) -> Dict[str, jnp.ndarray]:
@@ -218,7 +242,8 @@ def reference_readings(cfg: dict, train: dict, seed: int, batches,
     out_shard = None
     if shard is not None:
         out_shard = {k: shard(k, s) for k, s in shapes.items()}
-    p0 = jax.jit(lambda: init_params(cfg, seed), out_shardings=out_shard)()
+    key = seed_key(seed)
+    p0 = jax.jit(lambda k: init_params(cfg, k), out_shardings=out_shard)(key)
     zeros = jax.jit(lambda: {k: jnp.zeros(s, jnp.float32)
                              for k, s in shapes.items()},
                     out_shardings=out_shard)
@@ -226,22 +251,19 @@ def reference_readings(cfg: dict, train: dict, seed: int, batches,
     with jax.default_matmul_precision("highest"):
         step = jax.jit(lambda p, m, v, t, x, y: train_step(
             cfg, train, precision, chunks, p, m, v, t, x, y),
-            donate_argnums=(1, 2))
-        delta = jax.jit(lambda p: leaf_norms(
-            {k: p[k] - init_leaf(cfg, seed, k, s)
+            donate_argnums=(0, 1, 2))
+        delta = jax.jit(lambda p, key: leaf_norms(
+            {k: p[k] - init_leaf(cfg, key, k, s)
              for k, s in shapes.items()}))
         losses, g1 = [], None
         p = p0
         del p0
         for i, b in enumerate(batches):
-            p_new, m, v, loss, g = step(p, m, v, jnp.float32(i + 1),
-                                        jnp.asarray(b["tokens"]),
-                                        jnp.asarray(b["labels"]))
-            del p
-            p = p_new
+            p, m, v, loss, g = step(p, m, v, jnp.float32(i + 1),
+                                    jnp.asarray(b["tokens"]),
+                                    jnp.asarray(b["labels"]))
             losses.append(float(loss))
             if i == 0:
-                g1 = {k: float(x) for k, x in leaf_norms(g).items()}
-            del g
-        dn = {k: float(x) for k, x in delta(p).items()}
+                g1 = {k: float(x) for k, x in g.items()}
+        dn = {k: float(x) for k, x in delta(p, key).items()}
     return losses, g1, dn

@@ -116,6 +116,16 @@ def window(run: Run, driver, seconds: float, trace_units: int,
     run.window_compiles = clock.compiles
 
 
+def peak_bytes(device) -> int:
+    """The device's peak: its buffers' peak plus the peak of the space the
+    runtime reserves for the programs' temporaries, which
+    ``peak_bytes_in_use`` leaves out (a train step's gradients and
+    activations)."""
+    stats = device.memory_stats()
+    return (stats.get("peak_bytes_in_use", 0)
+            + stats.get("peak_bytes_reserved", 0))
+
+
 def run_cell(name: str, cell: dict, config: dict, traffic: dict,
              limits: dict, seed: int, seconds: float, trace: bool,
              devices: list, bench: dict) -> dict:
@@ -141,8 +151,8 @@ def run_cell(name: str, cell: dict, config: dict, traffic: dict,
         run.trace = Trace.load(logdir, [d.id for d in devices], driver.span)
         shutil.rmtree(logdir, ignore_errors=True)
 
-    memory_peak = max(d.memory_stats().get("peak_bytes_in_use", 0)
-                      for d in devices) if devices[0].platform == "tpu" else 0
+    memory_peak = max(peak_bytes(d) for d in devices) \
+        if devices[0].platform == "tpu" else 0
     metrics = read_metrics(run, metric_specs(bench, name, trace))
     checks = driver.verify()
     for c in checks:

@@ -7,8 +7,9 @@ For each seed it prints, as one JSON line, what the cell's comparison reads
 when the plain reference, computed one precision below the configuration's
 (bfloat16 for a float32 fleet, float8 for a bfloat16 model), stands in the
 program's place: the upper readings the limits are set under.  For a train
-cell it also reads the planted faults (half of each batch left out, the
-loss taken over the rest).  The benchmark's own runs never run this; the
+cell ``--faults`` reads the planted faults instead: half of each batch left
+out, the loss taken over the rest; and on more than one chip, the FSDP
+gradient exchange left out.  The benchmark's own runs never run this; the
 CPU tests in ``test_controls.py`` run the same controls at a tiny size.
 """
 from __future__ import annotations
@@ -45,18 +46,67 @@ def sweep_control(cfg, traffic, seed) -> dict:
     return out
 
 
-def half_batch(trainer_mod=None):
+def half_batch():
     """Plant a fault: the model's loss sees half of each batch, its mean
-    taken over the rest."""
+    taken over the rest: half of the rows, or of a single row's positions
+    (the first half, which causal attention lets stand alone)."""
     from repro.models import transformer
     orig = transformer.DecoderOnlyLM.loss
 
     def loss(self, params, batch):
-        half = batch["tokens"].shape[0] // 2
-        return orig(self, params, {k: v[:half] for k, v in batch.items()})
+        B, S = batch["tokens"].shape
+        cut = ((lambda v: v[:B // 2]) if B > 1
+               else (lambda v: v[:, :S // 2]))
+        return orig(self, params, {k: cut(v) for k, v in batch.items()})
 
     transformer.DecoderOnlyLM.loss = loss
     return lambda: setattr(transformer.DecoderOnlyLM, "loss", orig)
+
+
+def no_exchange():
+    """Plant a fault: the FSDP exchange of gradients left out.  Each chip
+    keeps its own slice of the gradient of its own rows of the batch where
+    the reduce-scatter over the data axis would give it the slice of the
+    sum over every chip's rows.  The model's loss is taken over each chip's
+    rows apart, and the gradient of chip i's part reaches only the slice
+    of each parameter that chip i holds (of a parameter held whole on every
+    chip, the first chip's)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.train import train_loop
+    orig = train_loop.build_train_step
+
+    def held_by(x, sharding, i, n):
+        """``x`` with its gradient let through on chip i's slice alone."""
+        dims = [d for d, ax in enumerate(sharding.spec)
+                if ax == "data" or (isinstance(ax, tuple) and "data" in ax)]
+        if not dims:
+            return x if i == 0 else jax.lax.stop_gradient(x)
+        d = dims[0]
+        size = x.shape[d] // n
+        pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, d)
+        mine = (pos >= i * size) & (pos < (i + 1) * size)
+        return jnp.where(mine, x, jax.lax.stop_gradient(x))
+
+    def build(model, train_cfg, rules, parallel):
+        n = int(rules.mesh.shape["data"])
+        shardings = rules.param_shardings(model.param_specs())
+        loss = type(model).loss
+
+        def local(params, batch):
+            rows = batch["tokens"].shape[0] // n
+            parts = [loss(model, jax.tree_util.tree_map(
+                         lambda x, s: held_by(x, s, i, n), params, shardings),
+                         {k: v[i * rows:(i + 1) * rows]
+                          for k, v in batch.items()})
+                     for i in range(n)]
+            return jax.tree_util.tree_map(lambda *xs: sum(xs) / n, *parts)
+
+        model.loss = local
+        return orig(model, train_cfg, rules, parallel)
+
+    train_loop.build_train_step = build
+    return lambda: setattr(train_loop, "build_train_step", orig)
 
 
 def train_readings(cfg, traffic, seed, devices, fault=None) -> dict:
@@ -84,7 +134,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--faults", action="store_true",
-                    help="train cells: read the planted half-batch fault")
+                    help="train cells: read the planted faults")
     args = ap.parse_args(argv)
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
@@ -98,8 +148,14 @@ def main(argv=None) -> int:
         if cfg["driver"] == "sweep":
             out = {"control": sweep_control(cfg, traffic, seed)}
         elif args.faults:
-            out = train_readings(cfg, traffic, seed, devices, half_batch)
-            out["fault"] = "half_batch"
+            faults = [half_batch] + ([no_exchange] if len(devices) > 1 else [])
+            for fault in faults:
+                t0 = time.time()
+                out = train_readings(cfg, traffic, seed, devices, fault)
+                out.update(fault=fault.__name__, seed=seed,
+                           seconds=time.time() - t0)
+                print(json.dumps(out), flush=True)
+            continue
         else:
             out = train_readings(cfg, traffic, seed, devices)
         out.update(seed=seed, seconds=time.time() - t0)

@@ -82,15 +82,56 @@ def test_a_cell_added_as_files_alone_is_found_by_name(tmp_path, monkeypatch):
     assert "sweep.scan_ms" not in [m["name"] for m in specs]
 
 
-def test_metrics_are_filtered_per_cell():
+CELLS = [w["name"] for w in bench_json()["workloads"]]
+SWEEP_CELLS = ["sweep.dp256.mc64", "sweep.dp2.mc1024"]
+TRAIN_CELLS = ["train.qwen3-4b.2l"]
+
+
+def reported(cell, trace):
     import bench.run as harness
+    return [m["name"] for m in harness.metric_specs(bench_json(), cell,
+                                                     trace=trace)]
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["e2e", "per_layer"])
+@pytest.mark.parametrize("cell", CELLS)
+def test_metrics_are_filtered_per_cell(cell, trace):
+    """A cell reports the metrics whose ``workloads`` list names it, and
+    those that have no such list."""
     b = bench_json()
-    e2e = [m["name"] for m in harness.metric_specs(b, "sweep.dp2.mc1024",
-                                                    trace=False)]
-    assert e2e == ["sweep_node_iters_per_s", "setup_s"]
-    pl = [m["name"] for m in harness.metric_specs(b, "sweep.dp256.mc64",
-                                                   trace=True)]
-    assert pl == ["sweep.scan_ms", "sweep.idle_share"]
+    group = b["per_layer"] if trace else b["end_to_end"]
+    want = [m["name"] for m in group
+            if "workloads" not in m or cell in m["workloads"]]
+    assert reported(cell, trace) == want
+    assert want
+
+
+@pytest.mark.parametrize("cell", SWEEP_CELLS)
+def test_sweep_cells_report_what_they_did(cell):
+    assert reported(cell, False) == ["sweep_node_iters_per_s", "setup_s"]
+    assert reported(cell, True) == [
+        "sweep.scan_ms", "sweep.idle_share", "sweep.sample_ms",
+        "sweep.workload_ms", "sweep.arrays_ms", "sweep.collect_ms",
+        "sweep.idle_unattributed_ms"]
+
+
+@pytest.mark.parametrize("cell", TRAIN_CELLS)
+def test_train_cells_report_no_sweep_metric(cell):
+    e2e = reported(cell, False)
+    assert "train_tokens_per_s" in e2e and "setup_s" in e2e
+    names = e2e + reported(cell, True)
+    assert not [n for n in names if n.startswith("sweep")], names
+    assert "train.mfu" in names
+
+
+def test_peak_bytes_adds_the_space_reserved_for_temporaries():
+    import bench.run as harness
+
+    class Device:
+        def memory_stats(self):
+            return {"peak_bytes_in_use": 7_128_674_816,
+                    "peak_bytes_reserved": 8_003_321_856}
+    assert harness.peak_bytes(Device()) == 15_131_996_672
 
 
 def _command(cwd, extra_env=None):
