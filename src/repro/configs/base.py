@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Tuple
 
 
 # --------------------------------------------------------------------------- #
@@ -18,17 +18,62 @@ from typing import Optional
 # --------------------------------------------------------------------------- #
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts block config (routed + optional shared experts)."""
+    """Mixture-of-experts block config (routed + optional shared experts).
+
+    ``capacity_factor=None`` routes without a capacity: every assignment
+    reaches its expert, whatever the imbalance.  ``held`` (first, count)
+    names the routed experts this device holds, as one chip of an
+    expert-parallel layer does: the router still scores all ``n_experts``,
+    and the layer computes the held experts' part of the result alone
+    (dropless routing only; None holds them all).
+    """
 
     n_experts: int
     top_k: int
     d_expert: int                      # per-expert hidden dim
     n_shared: int = 0                  # always-on shared experts
     router: str = "softmax"            # "softmax" | "sigmoid" (deepseek-v3)
-    capacity_factor: float = 1.25      # padded dispatch capacity (paper: padded GEMMs)
+    capacity_factor: Optional[float] = 1.25  # padded dispatch capacity (paper: padded GEMMs); None = dropless
     aux_loss_weight: float = 0.01      # load-balancing auxiliary loss
     first_k_dense: int = 0             # leading layers that use a dense MLP
     d_ff_dense: int = 0                # dense-MLP hidden dim for those layers
+    norm_topk: bool = True             # renormalise the top-k gates to sum to 1
+    held: Optional[Tuple[int, int]] = None   # (first, count) of the held experts
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        return self.held_range[1]
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values expand
+    from one RMS-normed latent of ``kv_lora_rank`` per token, and a rotary
+    key part of ``rope_dim`` is shared by every head.  Queries project
+    directly (no query low-rank)."""
+
+    kv_lora_rank: int
+    nope_dim: int                      # per-head query/key dims without rope
+    rope_dim: int                      # per-head query/key dims with rope
+    v_dim: int                         # per-head value dim
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling as DeepSeek-V2 applies it: frequencies blended
+    between interpolated and original by a ramp over the rotary dims, and
+    the softmax scale multiplied by ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -99,6 +144,8 @@ class ModelConfig:
     max_seq_len: int = 131_072
     # --- family extensions ---------------------------------------------------
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None    # latent attention in place of q/k/v
+    yarn: Optional[YarnConfig] = None  # rotary scaling (MLA's rope dims)
     ssm: Optional[SSMConfig] = None
     rwkv: Optional[RWKVConfig] = None
     vision: Optional[VisionConfig] = None
@@ -125,6 +172,17 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def qk_head_dim(self) -> int:
+        """Per-head width of queries and keys."""
+        if self.mla is not None:
+            return self.mla.nope_dim + self.mla.rope_dim
+        return self.head_dim
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.mla.v_dim if self.mla is not None else self.head_dim
+
+    @property
     def is_subquadratic(self) -> bool:
         """Whether long-context decode (long_500k) is feasible."""
         if self.family in ("rwkv",):
@@ -146,6 +204,12 @@ class ModelConfig:
         return mats * self.d_model * d_ff
 
     def _attn_params(self) -> int:
+        if self.mla is not None:
+            m, H, d = self.mla, self.n_heads, self.d_model
+            return (d * H * self.qk_head_dim                      # Wq
+                    + d * (m.kv_lora_rank + m.rope_dim)           # Wkv_a
+                    + m.kv_lora_rank * H * (m.nope_dim + m.v_dim)  # Wkv_b
+                    + H * m.v_dim * d)                            # Wo
         p = self.d_model * self.q_dim            # Wq
         p += 2 * self.d_model * self.kv_dim      # Wk, Wv
         p += self.q_dim * self.d_model           # Wo
@@ -197,7 +261,7 @@ class ModelConfig:
             if layer_idx < self.moe.first_k_dense:
                 p += self._mlp_params(self.moe.d_ff_dense or self.d_ff)
             else:
-                n = self.moe.n_experts + self.moe.n_shared
+                n = self.moe.n_held + self.moe.n_shared
                 p += n * self._mlp_params(self.moe.d_expert)
                 p += self.d_model * self.moe.n_experts   # router
         else:
@@ -351,6 +415,9 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
             first_k_dense=min(cfg.moe.first_k_dense, 1),
             d_ff_dense=128 if cfg.moe.first_k_dense else 0,
         )
+    if cfg.mla is not None:
+        kw["mla"] = dataclasses.replace(cfg.mla, kv_lora_rank=32, nope_dim=16,
+                                        rope_dim=8, v_dim=16)
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=8)
     if cfg.rwkv is not None:

@@ -1,6 +1,9 @@
-"""deepseek-v3-16b — the paper's MoE workload (§VII-C, trained with Primus/
-torchtitan, 8-way expert parallel).  DeepSeek-MoE-16B dims with V3-style
-sigmoid routing.  [arXiv:2412.19437 + 2401.06066]"""
+"""deepseek-v3-16b — a stand-in for the paper's MoE workload (§VII-C,
+trained with Primus/torchtitan, 8-way expert parallel): plain MHA at
+DeepSeek-MoE-16B's widths with V3-style sigmoid routing, not latent
+attention.  The simulator's kernel schedules use it.  The latent-attention
+model at these widths, the one the trainer runs, is ``deepseek-v2-lite``.
+[arXiv:2412.19437 + 2401.06066]"""
 from repro.configs.base import ModelConfig, MoEConfig
 
 CONFIG = ModelConfig(

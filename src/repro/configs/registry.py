@@ -1,6 +1,7 @@
 """Architecture registry: ``--arch <id>`` -> ModelConfig.
 
-The 10 assigned architectures plus the paper's own three workloads.  IDs match
+The 10 assigned architectures, the paper's own three workloads and the
+latent-attention MoE the trainer benchmarks (deepseek-v2-lite).  IDs match
 the assignment exactly (dots and dashes); module names are sanitized.
 """
 from __future__ import annotations
@@ -28,6 +29,7 @@ _ARCH_MODULES: Dict[str, str] = {
     "llama3.1-8b":          "llama3_1_8b",
     "mistral-7b":           "mistral_7b",
     "deepseek-v3-16b":      "deepseek_v3_16b",
+    "deepseek-v2-lite":     "deepseek_v2_lite",
 }
 
 ASSIGNED_ARCHS: List[str] = list(_ARCH_MODULES)[:10]

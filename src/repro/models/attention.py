@@ -1,6 +1,8 @@
 """Attention: GQA SDPA (grouped, no materialized KV repeat), qk-norm, biases,
-causal/sliding/bidirectional masks, cross-attention, and decode over KV caches
-(full-length or sliding-window ring buffers).
+causal/sliding/bidirectional masks, cross-attention, multi-head latent
+attention (DeepSeek-V2's MLA: keys and values from a normed latent, expanded
+per head), and decode over KV caches (full-length or sliding-window ring
+buffers; MLA decodes over the expanded per-head keys and values).
 
 Default backend is plain XLA einsums (what the dry-run lowers for the 512-chip
 mesh); the Pallas flash-attention kernel from ``repro.kernels`` can be swapped
@@ -8,12 +10,14 @@ in with ``set_attention_impl("pallas")`` (validated in interpret mode on CPU).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ParamSpec, apply_rope, rmsnorm, rope_freqs
+from repro.models.common import (ParamSpec, apply_rope, rmsnorm, rope_freqs,
+                                 yarn_mscale)
 
 _ATTN_IMPL = "chunked"
 
@@ -42,6 +46,8 @@ def attn_specs(cfg, kv_src_dim: Optional[int] = None) -> Dict[str, ParamSpec]:
     the TP-sharded flattened head dims (fallback to replicated handled by the
     rules engine when head counts don't divide the mesh).
     """
+    if cfg.mla is not None:
+        return mla_specs(cfg)
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     src = kv_src_dim if kv_src_dim is not None else d
     s: Dict[str, ParamSpec] = {
@@ -60,11 +66,46 @@ def attn_specs(cfg, kv_src_dim: Optional[int] = None) -> Dict[str, ParamSpec]:
     return s
 
 
+def mla_specs(cfg) -> Dict[str, ParamSpec]:
+    """MLA projections: queries per head; one joint projection to the KV
+    latent and the shared rotary key part; the latent's norm; the latent's
+    expansion to per-head keys (no rope) and values; the output."""
+    m, d, H = cfg.mla, cfg.d_model, cfg.n_heads
+    return {
+        "wq": ParamSpec((d, H * cfg.qk_head_dim), ("embed", "heads")),
+        "wkv_a": ParamSpec((d, m.kv_lora_rank + m.rope_dim), ("embed", None)),
+        "kv_norm": ParamSpec((m.kv_lora_rank,), (None,), "ones"),
+        "wkv_b": ParamSpec((m.kv_lora_rank, H * (m.nope_dim + m.v_dim)),
+                           (None, "heads")),
+        "wo": ParamSpec((H * m.v_dim, d), ("heads", "embed")),
+    }
+
+
+def softmax_scale(cfg) -> float:
+    """Scale of the attention scores: ``qk_head_dim ** -0.5``, times YaRN's
+    ``mscale(factor, mscale_all_dim) ** 2`` where the config scales rope."""
+    scale = cfg.qk_head_dim ** -0.5
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(cfg, x, positions):
+    cos, sin = rope_freqs(positions, x.shape[-1], cfg.rope_theta, cfg.yarn)
+    return apply_rope(x, cos, sin)
+
+
 # --------------------------------------------------------------------------- #
 # Projections
 # --------------------------------------------------------------------------- #
 def project_q(cfg, p, x, positions=None):
     B, S, _ = x.shape
+    if cfg.mla is not None:
+        q = (x @ p["wq"].astype(x.dtype)).reshape(
+            B, S, cfg.n_heads, cfg.qk_head_dim)
+        nope = cfg.mla.nope_dim
+        return jnp.concatenate(
+            [q[..., :nope], _rope(cfg, q[..., nope:], positions)], -1)
     q = x @ p["wq"].astype(x.dtype)
     if "bq" in p:
         q = q + p["bq"].astype(x.dtype)
@@ -79,6 +120,17 @@ def project_q(cfg, p, x, positions=None):
 
 def project_kv(cfg, p, x, positions=None):
     B, S, _ = x.shape
+    if cfg.mla is not None:
+        m, H = cfg.mla, cfg.n_heads
+        kv_a = x @ p["wkv_a"].astype(x.dtype)
+        c = rmsnorm(kv_a[..., :m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+        k_pe = _rope(cfg, kv_a[..., None, m.kv_lora_rank:], positions)
+        kv = (c @ p["wkv_b"].astype(x.dtype)).reshape(
+            B, S, H, m.nope_dim + m.v_dim)
+        k = jnp.concatenate(
+            [kv[..., :m.nope_dim],
+             jnp.broadcast_to(k_pe, (B, S, H, m.rope_dim))], -1)
+        return k, kv[..., m.nope_dim:]
     k = x @ p["wk"].astype(x.dtype)
     v = x @ p["wv"].astype(x.dtype)
     if "bk" in p:
@@ -113,29 +165,33 @@ def make_mask(Sq: int, Sk: int, *, causal: bool, window: int = 0,
 # --------------------------------------------------------------------------- #
 # Core SDPA (grouped-query, fp32 softmax)
 # --------------------------------------------------------------------------- #
-def sdpa(q, k, v, mask=None):
-    """q: (B,Sq,H,D), k/v: (B,Sk,kvH,D); returns (B,Sq,H,D).
+def sdpa(q, k, v, mask=None, scale=None):
+    """q/k: (B,Sq|Sk,H|kvH,D), v: (B,Sk,kvH,Dv); returns (B,Sq,H,Dv).
+    ``scale`` defaults to ``D ** -0.5``.
 
     GQA is computed grouped — q reshaped to (kvH, group) — so KV is never
     materialized H-wide (keeps HBM traffic and TP resharding minimal).
     """
+    B, Sq, H, D = q.shape
     if _ATTN_IMPL == "pallas":
+        if v.shape[-1] != D or (scale is not None and scale != D ** -0.5):
+            raise NotImplementedError(
+                "the Pallas flash kernel takes one head width and D**-0.5")
         from repro.kernels.flash_attention import ops as fa_ops
         return fa_ops.flash_attention(q, k, v, mask=mask)
-    B, Sq, H, D = q.shape
     kvH = k.shape[2]
     G = H // kvH
     qg = q.reshape(B, Sq, kvH, G, D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32)
-    scores = scores * (D ** -0.5)
+    scores = scores * (D ** -0.5 if scale is None else scale)
     if mask is not None:
         while mask.ndim < scores.ndim:
             mask = mask[None]
         scores = jnp.where(mask, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(v.dtype), v)
-    return out.reshape(B, Sq, H, D)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 _FLASH_REMAT = True
@@ -150,11 +206,14 @@ def set_flash_remat(on: bool) -> None:
 
 
 def sdpa_flash(q, k, v, *, causal=True, window_eff=0, chunk: int = 1024,
-               q_offset=0):
+               q_offset=0, scale=None):
     """XLA flash-structured attention: lax.scan over KV chunks with online
     softmax — O(S·chunk) live scores instead of O(S²).  window_eff may be a
-    traced scalar (hymba per-layer global/sliding selection)."""
+    traced scalar (hymba per-layer global/sliding selection).  Values may be
+    narrower than queries and keys (MLA: 128 against 192); ``scale``
+    defaults to ``D ** -0.5`` of the query width."""
     B, Sq, H, D = q.shape
+    Dv = v.shape[-1]
     Sk, kvH = k.shape[1], k.shape[2]
     G = H // kvH
     C = min(chunk, Sk)
@@ -170,11 +229,11 @@ def sdpa_flash(q, k, v, *, causal=True, window_eff=0, chunk: int = 1024,
     # (tiny) — avoids the partitioner's seq<->head all-to-all reshard.
     qg = constrain(qg, "act_batch", "act_seq", None, None, None)
     kc = k.reshape(B, n, C, kvH, D).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n, C, kvH, D).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, n, C, kvH, Dv).transpose(1, 0, 2, 3, 4)
     kc = constrain(kc, None, "act_batch", None, None, None)
     vc = constrain(vc, None, "act_batch", None, None, None)
     qi = (jnp.arange(Sq) + q_offset)[:, None]                 # (Sq, 1)
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     def body(carry, inp):
         m, l, acc = carry
@@ -203,11 +262,11 @@ def sdpa_flash(q, k, v, *, causal=True, window_eff=0, chunk: int = 1024,
             prevent_cse=False)
     m0 = jnp.full((B, kvH, G, Sq, 1), -1e30, jnp.float32)
     l0 = jnp.zeros((B, kvH, G, Sq, 1), jnp.float32)
-    a0 = jnp.zeros((B, kvH, G, Sq, D), jnp.float32)
+    a0 = jnp.zeros((B, kvH, G, Sq, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0), (jnp.arange(n), kc, vc))
     out = acc / jnp.maximum(l, 1e-30)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, D).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dv).astype(q.dtype)
 
 
 def sdpa_stub(q, k, v):
@@ -222,14 +281,15 @@ def sdpa_stub(q, k, v):
     return jnp.broadcast_to(out, (B, Sq, H, D)) + 0.0 * q
 
 
-def sdpa_auto(q, k, v, *, causal, window_eff=0, q_offset=0, mask=None):
+def sdpa_auto(q, k, v, *, causal, window_eff=0, q_offset=0, mask=None,
+              scale=None):
     """Dispatch: chunked flash structure for multi-token attention, naive
     masked SDPA otherwise (decode / explicit masks / pallas)."""
     if _ATTN_IMPL == "stub" and q.shape[1] > 1:
         return sdpa_stub(q, k, v)
     if (_ATTN_IMPL == "chunked" and q.shape[1] > 1 and mask is None):
         return sdpa_flash(q, k, v, causal=causal, window_eff=window_eff,
-                          q_offset=q_offset)
+                          q_offset=q_offset, scale=scale)
     if mask is None:
         Sq, Sk = q.shape[1], k.shape[1]
         qi = (jnp.arange(Sq) + q_offset)[:, None]
@@ -239,7 +299,7 @@ def sdpa_auto(q, k, v, *, causal, window_eff=0, q_offset=0, mask=None):
             mask &= ki <= qi
         if not (isinstance(window_eff, int) and window_eff == 0):
             mask &= (window_eff == 0) | (ki > qi - window_eff)
-    return sdpa(q, k, v, mask)
+    return sdpa(q, k, v, mask, scale)
 
 
 def attention(cfg, p, x, positions, mask, kv_x=None, kv_positions=None,
@@ -247,17 +307,22 @@ def attention(cfg, p, x, positions, mask, kv_x=None, kv_positions=None,
     """Full self/cross attention for training & prefill.  Returns (B,S,d).
 
     mask=None + causal/window_eff semantics -> flash-structured path;
-    an explicit mask array forces the naive path.
+    an explicit mask array forces the naive path.  MLA runs under the
+    ``mla`` named scope.
     """
-    q = project_q(cfg, p, x, positions)
-    src = kv_x if kv_x is not None else x
-    kpos = None if kv_x is not None else positions
-    if kv_x is not None and kv_positions is not None:
-        kpos = kv_positions
-    k, v = project_kv(cfg, p, src, kpos)
-    out = sdpa_auto(q, k, v, causal=causal, window_eff=window_eff, mask=mask)
-    B, S = x.shape[:2]
-    return out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(x.dtype)
+    scope = (jax.named_scope("mla") if cfg.mla is not None
+             else contextlib.nullcontext())
+    with scope:
+        q = project_q(cfg, p, x, positions)
+        src = kv_x if kv_x is not None else x
+        kpos = None if kv_x is not None else positions
+        if kv_x is not None and kv_positions is not None:
+            kpos = kv_positions
+        k, v = project_kv(cfg, p, src, kpos)
+        out = sdpa_auto(q, k, v, causal=causal, window_eff=window_eff,
+                        mask=mask, scale=softmax_scale(cfg))
+        B, S = x.shape[:2]
+        return out.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -303,6 +368,6 @@ def decode_attention(cfg, p, x, pos, k_cache, v_cache, *, ring: bool,
             if is_global is not None:
                 win_ok = win_ok | is_global
             valid &= win_ok
-    out = sdpa(q, k_cache, v_cache, valid[None, None, :])
-    out = out.reshape(B, 1, cfg.q_dim) @ p["wo"].astype(x.dtype)
+    out = sdpa(q, k_cache, v_cache, valid[None, None, :], softmax_scale(cfg))
+    out = out.reshape(B, 1, -1) @ p["wo"].astype(x.dtype)
     return out, k_cache, v_cache

@@ -128,12 +128,47 @@ def softcap(logits, cap: float):
 # --------------------------------------------------------------------------- #
 # RoPE
 # --------------------------------------------------------------------------- #
-def rope_freqs(positions, head_dim: int, theta: float):
-    """cos/sin tables for given positions: (..., head_dim//2) each."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn):
+    """DeepSeek-V2's YaRN frequencies: original ones ``f`` below the ramp,
+    ``f / factor`` above it, blended linearly across it.  The ramp runs over
+    the frequency indices between the dims that turn ``beta_fast`` and
+    ``beta_slow`` times in ``original_max_len`` positions."""
+    def dim_at(turns):
+        return (head_dim * math.log(yarn.original_max_len
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim_at(yarn.beta_fast)), 0)
+    hi = min(math.ceil(dim_at(yarn.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    f = 1.0 / theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                        / head_dim)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    return f / yarn.factor * ramp + f * (1.0 - ramp)
+
+
+def rope_freqs(positions, head_dim: int, theta: float, yarn=None):
+    """cos/sin tables for given positions: (..., head_dim//2) each; with
+    ``yarn`` (a ``YarnConfig``) its frequencies and cos/sin factor."""
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                               / head_dim))
+        amp = 1.0
+    else:
+        inv = yarn_inv_freq(head_dim, theta, yarn)
+        amp = (yarn_mscale(yarn.factor, yarn.mscale)
+               / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * inv       # (..., hd/2)
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if amp != 1.0:
+        cos, sin = amp * cos, amp * sin
+    return cos, sin
 
 
 def apply_rope(x, cos, sin):

@@ -1,12 +1,25 @@
 """Mixture-of-experts block: top-k router (softmax or deepseek-v3 sigmoid),
-capacity-based padded dispatch (sort + scatter, token-dropping — the padded
-grouped GEMM the paper's platform uses, §VII-C), shared experts, and the
-load-balancing auxiliary loss.
+shared experts, the per-sequence load-balancing auxiliary loss, and two
+dispatches, chosen by the config:
 
-Expert compute is an (E, C, d) x (E, d, h) grouped batched matmul — sharded
-expert-parallel over 'model' when E divides the axis, else TP over the expert
-hidden dim (grok-1: 8 experts on a 16-way axis).  The Pallas grouped-GEMM
-kernel in ``repro.kernels.moe_gemm`` implements the same contraction for TPU.
+* capacity (``capacity_factor`` set): sort + scatter into (E, C) slots,
+  overflow to a trash slot (token-dropping — the padded grouped GEMM the
+  paper's platform uses, §VII-C).  Expert compute is an (E, C, d) x
+  (E, d, h) batched matmul — sharded expert-parallel over 'model' when E
+  divides the axis, else TP over the expert hidden dim (grok-1: 8 experts on
+  a 16-way axis).  The Pallas grouped-GEMM kernel in
+  ``repro.kernels.moe_gemm`` implements the same contraction for TPU.
+* dropless (``capacity_factor=None``): every assignment to an expert this
+  device holds (``MoEConfig.held``) reaches it, whatever the imbalance.
+  The router scores every expert; the layer computes the held experts' part
+  of the result, as one chip of an expert-parallel layer does (on one chip,
+  without the exchange).  Grouped products (``jax.lax.ragged_dot``) run
+  over the held experts' rows only.
+
+The dropless layer works under the named scopes ``moe.route``,
+``moe.dispatch``, ``moe.experts``, ``moe.combine`` and ``moe.shared``, and
+counts ``moe_rows_held`` (assignments to held experts: the rows its grouped
+products compute) beside the auxiliary loss.
 """
 from __future__ import annotations
 
@@ -21,12 +34,12 @@ from repro.models.mlp import mlp, mlp_specs
 
 def moe_specs(cfg) -> Dict[str, ParamSpec]:
     m = cfg.moe
-    d, E, h = cfg.d_model, m.n_experts, m.d_expert
+    d, E, h, n = cfg.d_model, m.n_experts, m.d_expert, m.n_held
     s: Dict[str, ParamSpec] = {
         "router": ParamSpec((d, E), ("embed", "experts"), "normal", 0.02),
-        "wg": ParamSpec((E, d, h), ("experts", "embed", "expert_ffn")),
-        "wu": ParamSpec((E, d, h), ("experts", "embed", "expert_ffn")),
-        "wd": ParamSpec((E, h, d), ("experts", "expert_ffn", "embed")),
+        "wg": ParamSpec((n, d, h), ("experts", "embed", "expert_ffn")),
+        "wu": ParamSpec((n, d, h), ("experts", "embed", "expert_ffn")),
+        "wd": ParamSpec((n, h, d), ("experts", "expert_ffn", "embed")),
     }
     if m.n_shared:
         # shared experts are always-on: computed as one fused wide MLP
@@ -39,23 +52,26 @@ def moe_specs(cfg) -> Dict[str, ParamSpec]:
 
 
 def _route(cfg, logits):
-    """-> (gates (T,k), idx (T,k), aux_loss scalar)."""
+    """logits (B, S, E) -> (gates (B,S,k), idx (B,S,k), aux_loss scalar).
+
+    The auxiliary loss is DeepSeek's expert-level balance loss, taken per
+    sequence and averaged over the batch: ``weight * sum_e f_e * P_e``, with
+    ``f_e = E * count_e / (S * k)`` from the sequence's assignments and
+    ``P_e`` its mean router probability."""
     m = cfg.moe
     if m.router == "sigmoid":                      # deepseek-v3 style
         scores = jax.nn.sigmoid(logits)
         gates, idx = jax.lax.top_k(scores, m.top_k)
-        gates = gates / (gates.sum(-1, keepdims=True) + 1e-9)
         probs = scores / (scores.sum(-1, keepdims=True) + 1e-9)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         gates, idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk:
         gates = gates / (gates.sum(-1, keepdims=True) + 1e-9)
-    # load-balancing aux loss: E * sum_e f_e * P_e
-    T = logits.shape[0]
-    one_hot = jax.nn.one_hot(idx, m.n_experts, dtype=jnp.float32)  # (T,k,E)
-    f_e = one_hot.sum((0, 1)) / (T * m.top_k)
-    p_e = probs.mean(0)
-    aux = m.aux_loss_weight * m.n_experts * jnp.sum(f_e * p_e)
+    S = logits.shape[1]
+    counts = jax.nn.one_hot(idx, m.n_experts, dtype=jnp.float32).sum((1, 2))
+    f_e = counts * m.n_experts / (S * m.top_k)              # (B, E)
+    aux = m.aux_loss_weight * jnp.mean(jnp.sum(f_e * probs.mean(1), -1))
     return gates, idx, aux
 
 
@@ -105,38 +121,108 @@ def _dispatch_combine_local(cfg, p, xs, gates, idx):
     return jnp.zeros((T, d), xs.dtype).at[st].add(back)
 
 
-def moe_forward(cfg, p, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
-    from repro.parallel.act import constrain, data_extent
+def _dropless(cfg, p, xs, gates, idx):
+    """Every assignment to a held expert, through grouped products over the
+    held experts' rows.
 
+    xs: (T, d); gates/idx: (T, k).  Each of the T*k assignments gets a row
+    of its own in a buffer laid out by group: the held experts' rows first,
+    expert after expert, then the assignments to experts held elsewhere,
+    which no product reads.  The buffer holds all T*k rows, so no imbalance
+    can overflow it.  Returns the routed part of the layer's output (T, d)
+    and the rows held.
+
+    The grouped products define no row outside their groups (XLA's TPU
+    ragged dot leaves such rows, and their cotangents, as they find them),
+    so every value read past the groups is selected away, never
+    multiplied by zero.
+    """
+    m = cfg.moe
+    T, d = xs.shape
+    k = m.top_k
+    first, n = m.held_range
+    A = T * k
+    with jax.named_scope("moe.dispatch"):
+        e = idx.reshape(A) - first
+        held = (e >= 0) & (e < n)
+        group = jnp.where(held, e, n)              # group n: held elsewhere
+        one_hot = jax.nn.one_hot(group, n + 1, dtype=jnp.int32)
+        rank = jnp.sum((jnp.cumsum(one_hot, 0) - 1) * one_hot, 1)
+        sizes = one_hot.sum(0)[:n]
+        starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                  jnp.cumsum(sizes)])
+        dest = starts[group] + rank
+        rows = jnp.where(held[:, None], jnp.repeat(xs, k, axis=0), 0)
+        buf = jnp.zeros((A, d), xs.dtype).at[dest].set(
+            rows, unique_indices=True)
+    with jax.named_scope("moe.experts"):
+        dt = xs.dtype
+        live = (jnp.arange(A) < starts[n])[:, None]
+
+        def grouped(lhs, w):
+            return jnp.where(
+                live, jax.lax.ragged_dot(lhs, w.astype(dt), sizes), 0)
+
+        act = activation_fn(cfg.activation)
+        h = act(grouped(buf, p["wg"])) * grouped(buf, p["wu"])
+        y = grouped(h, p["wd"])
+    with jax.named_scope("moe.combine"):
+        yk = jnp.where(held.reshape(T, k, 1),
+                       y.at[dest].get(unique_indices=True).reshape(T, k, d),
+                       0)
+        out = jnp.sum(yk.astype(jnp.float32) * gates.reshape(T, k, 1),
+                      axis=1)
+    return out.astype(dt), starts[n].astype(jnp.float32)
+
+
+def stat_names(cfg) -> Tuple[str, ...]:
+    """Names of the scalars ``moe_forward`` returns for ``cfg``."""
+    if cfg.moe.capacity_factor is None:
+        return ("aux_loss", "moe_rows_held")
+    return ("aux_loss",)
+
+
+def moe_forward(cfg, p, x) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """x: (B, S, d) -> (out (B, S, d), {name: scalar} of ``stat_names``)."""
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
     xf = x.reshape(T, d)
 
-    logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    gates, idx, aux = _route(cfg, logits)
+    with jax.named_scope("moe.route"):
+        logits = (xf.astype(jnp.float32)
+                  @ p["router"].astype(jnp.float32))
+        gates, idx, aux = _route(cfg, logits.reshape(B, S, -1))
+        gates, idx = gates.reshape(T, -1), idx.reshape(T, -1)
+    stats = {"aux_loss": aux}
 
-    from repro.parallel.moe_shard_map import get_moe_dispatch
-    from repro.parallel.act import _state
-    mesh = getattr(_state, "mesh", None)
-    rules = getattr(_state, "rules", None)
-    if get_moe_dispatch() == "shard_map" and mesh is not None:
-        from repro.parallel.moe_shard_map import moe_forward_shard_map
-        out = moe_forward_shard_map(
-            cfg, p, x, gates.reshape(B, S, -1), idx.reshape(B, S, -1),
-            mesh, rules.get("act_batch", ()) if rules else ())
-        out = out.reshape(T, d)
+    if m.capacity_factor is None:
+        out, stats["moe_rows_held"] = _dropless(cfg, p, xf, gates, idx)
     else:
-        # global-capacity pjit dispatch (per-data-shard vmapped dispatch was
-        # measured NET-NEGATIVE on the 16x16 mesh — EXPERIMENTS.md §Perf
-        # G2/G3: the partitioner replicates the vmapped scatter's backward);
-        # the forced-local shard_map layout is G5.
-        out = _dispatch_combine_local(cfg, p, xf, gates, idx)
+        if m.held is not None:
+            raise ValueError("held experts need dropless routing "
+                             "(capacity_factor=None)")
+        from repro.parallel.moe_shard_map import get_moe_dispatch
+        from repro.parallel.act import _state
+        mesh = getattr(_state, "mesh", None)
+        rules = getattr(_state, "rules", None)
+        if get_moe_dispatch() == "shard_map" and mesh is not None:
+            from repro.parallel.moe_shard_map import moe_forward_shard_map
+            out = moe_forward_shard_map(
+                cfg, p, x, gates.reshape(B, S, -1), idx.reshape(B, S, -1),
+                mesh, rules.get("act_batch", ()) if rules else ())
+            out = out.reshape(T, d)
+        else:
+            # global-capacity pjit dispatch (per-data-shard vmapped dispatch
+            # was measured NET-NEGATIVE on the 16x16 mesh — EXPERIMENTS.md
+            # §Perf G2/G3: the partitioner replicates the vmapped scatter's
+            # backward); the forced-local shard_map layout is G5.
+            out = _dispatch_combine_local(cfg, p, xf, gates, idx)
 
     if m.n_shared:
-        out = out + mlp(cfg, p["shared"], xf)
-    return out.reshape(B, S, d), aux
+        with jax.named_scope("moe.shared"):
+            out = out + mlp(cfg, p["shared"], xf)
+    return out.reshape(B, S, d), stats
 
 
 def moe_or_mlp_specs(cfg, layer_is_dense: bool):

@@ -19,7 +19,7 @@ from repro.models import ssm as ssm_mod
 from repro.models.common import (ParamSpec, apply_norm, cross_entropy_loss,
                                  norm_spec, pad_vocab, softcap, stack_specs,
                                  take_embedding, tree_get)
-from repro.models.moe import moe_forward, moe_or_mlp_specs
+from repro.models.moe import moe_forward, moe_or_mlp_specs, stat_names
 from repro.models.mlp import mlp
 from repro.parallel.act import shard_residual
 
@@ -112,13 +112,17 @@ class DecoderOnlyLM:
                        + apply_norm(cfg, p["out_norm_ssm"], s))
         return a
 
+    def _is_moe(self, dense_mlp: bool) -> bool:
+        return self.cfg.moe is not None and not dense_mlp
+
     def _ffn(self, p, x, dense_mlp: bool):
+        """-> (out, {name: scalar}): the auxiliary loss, and an expert
+        layer's counters (``moe.stat_names``)."""
         cfg = self.cfg
         h = apply_norm(cfg, p["ln2"], x)
-        if cfg.moe is not None and not dense_mlp:
-            out, aux = moe_forward(cfg, p["ffn"], h)
-            return out, aux
-        return mlp(cfg, p["ffn"], h), jnp.zeros((), jnp.float32)
+        if self._is_moe(dense_mlp):
+            return moe_forward(cfg, p["ffn"], h)
+        return mlp(cfg, p["ffn"], h), {"aux_loss": jnp.zeros((), jnp.float32)}
 
     def _block(self, p, x, positions, is_global, dense_mlp: bool):
         we = self._window_eff(is_global)
@@ -129,30 +133,34 @@ class DecoderOnlyLM:
         x = x + shard_residual(
             self._mixer(p, x, positions, we, dense_mlp, is_global))
         x = shard_residual(x)
-        f, aux = self._ffn(p, x, dense_mlp)
-        return shard_residual(x + shard_residual(f)), aux
+        f, stats = self._ffn(p, x, dense_mlp)
+        return shard_residual(x + shard_residual(f)), stats
 
     def _scan_group(self, gparams, x, positions, flags, dense_mlp: bool,
                     n_layers: int):
-        """Run one layer group under scan + remat; returns (x, aux_sum)."""
+        """Run one layer group under scan + remat; returns (x, stats): the
+        group's sums of what each layer's ``_ffn`` returns beside its
+        output."""
         block = self._block
 
         def body(carry, xs):
-            x, aux = carry
+            x, stats = carry
             lp, is_g = xs
-            x, a = block(lp, x, positions, is_g, dense_mlp)
-            return (x, aux + a), None
+            x, s = block(lp, x, positions, is_g, dense_mlp)
+            return (x, {k: v + s[k] for k, v in stats.items()}), None
 
         body = jax.checkpoint(body, policy=REMAT_POLICIES[self.remat],
                               prevent_cse=False)
+        names = (stat_names(self.cfg) if self._is_moe(dense_mlp)
+                 else ("aux_loss",))
+        stats = {k: jnp.zeros((), jnp.float32) for k in names}
         if self.scan_layers:
-            (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       (gparams, flags))
+            (x, stats), _ = jax.lax.scan(body, (x, stats), (gparams, flags))
         else:
-            aux = jnp.zeros((), jnp.float32)
             for i in range(n_layers):
-                (x, aux), _ = body((x, aux), (tree_get(gparams, i), flags[i]))
-        return x, aux
+                (x, stats), _ = body((x, stats),
+                                     (tree_get(gparams, i), flags[i]))
+        return x, stats
 
     # --------------------------------------------------------------- forward
     def _embed(self, params, tokens, pos_offset=0):
@@ -172,15 +180,16 @@ class DecoderOnlyLM:
                           for i in range(lo, hi)], bool)
 
     def _run_layers(self, params, x, positions):
-        aux = jnp.zeros((), jnp.float32)
+        stats = {}
         lo = 0
         for gi, (n, dense) in enumerate(self.layer_groups()):
             flags = self._global_flags(lo, lo + n)
-            x, a = self._scan_group(params[f"g{gi}"], x, positions, flags,
+            x, s = self._scan_group(params[f"g{gi}"], x, positions, flags,
                                     dense, n)
-            aux = aux + a
+            for k, v in s.items():
+                stats[k] = stats.get(k, 0.0) + v
             lo += n
-        return x, aux
+        return x, stats
 
     def _logits(self, params, x):
         cfg = self.cfg
@@ -194,21 +203,28 @@ class DecoderOnlyLM:
             logits = jnp.where(pad_mask, logits, -1e30)
         return logits
 
-    def forward(self, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def _forward(self, params, batch):
+        """-> (logits, stats): the layers' summed ``_ffn`` scalars."""
         tokens = batch["tokens"]
         S = tokens.shape[1]
         positions = jnp.arange(S, dtype=jnp.int32)
         x = self._embed(params, tokens)
-        x, aux = self._run_layers(params, x, positions)
-        return self._logits(params, x), aux
+        x, stats = self._run_layers(params, x, positions)
+        return self._logits(params, x), stats
+
+    def forward(self, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        logits, stats = self._forward(params, batch)
+        return logits, stats["aux_loss"]
 
     def loss(self, params, batch):
-        logits, aux = self.forward(params, batch)
+        """Cross-entropy (+ z-loss) + auxiliary loss; the metrics carry the
+        expert layers' counters too."""
+        logits, stats = self._forward(params, batch)
         loss, metrics = cross_entropy_loss(
             logits, batch["labels"],
             z_loss_weight=getattr(self, "z_loss_weight", 1e-4))
-        metrics["aux_loss"] = aux
-        return loss + aux, metrics
+        metrics.update(stats)
+        return loss + stats["aux_loss"], metrics
 
     # ---------------------------------------------------------------- decode
     @property
@@ -225,9 +241,9 @@ class DecoderOnlyLM:
         W = self.cache_window
         cache: Dict[str, Any] = {
             "k": jnp.zeros((cfg.n_layers, batch, W, cfg.n_kv_heads,
-                            cfg.head_dim), dtype),
+                            cfg.qk_head_dim), dtype),
             "v": jnp.zeros((cfg.n_layers, batch, W, cfg.n_kv_heads,
-                            cfg.head_dim), dtype),
+                            cfg.v_head_dim), dtype),
             "pos": jnp.zeros((), jnp.int32),
         }
         if cfg.family == "hybrid":
@@ -287,8 +303,9 @@ class DecoderOnlyLM:
                 q = attn.project_q(cfg, lp["attn"], h, positions)
                 k, v = attn.project_kv(cfg, lp["attn"], h, positions)
                 a = attn.sdpa_auto(q, k, v, causal=True,
-                                   window_eff=self._window_eff(is_g))
-                a = a.reshape(B, S, cfg.q_dim) @ lp["attn"]["wo"].astype(x.dtype)
+                                   window_eff=self._window_eff(is_g),
+                                   scale=attn.softmax_scale(cfg))
+                a = a.reshape(B, S, -1) @ lp["attn"]["wo"].astype(x.dtype)
                 ys = {"k": k, "v": v}
                 if cfg.family == "hybrid":
                     s_out, s_state = ssm_prefill(cfg, lp["ssm"], h)
@@ -296,8 +313,8 @@ class DecoderOnlyLM:
                                + apply_norm(cfg, lp["out_norm_ssm"], s_out))
                     ys["ssm"] = s_state
                 x = x + a
-                f, a2 = self._ffn(lp, x, dense)
-                return (x + f, aux + a2), ys
+                f, s = self._ffn(lp, x, dense)
+                return (x + f, aux + s["aux_loss"]), ys
 
             body = jax.checkpoint(body, policy=REMAT_POLICIES[self.remat],
                                   prevent_cse=False, static_argnums=())

@@ -11,6 +11,7 @@ PUBLISHED_B = {
     "deepseek-7b": (6.9, 0.05), "hymba-1.5b": (1.5, 0.15),
     "llama-3.2-vision-90b": (90, 0.05), "rwkv6-3b": (3.1, 0.05),
     "llama3.1-8b": (8.0, 0.05), "mistral-7b": (7.2, 0.05),
+    "deepseek-v2-lite": (15.7, 0.05),
 }
 
 

@@ -13,11 +13,12 @@ from repro.models.common import init_params
 
 FAMS = ["qwen3-4b", "deepseek-7b", "hymba-1.5b", "rwkv6-3b",
         "whisper-medium", "llama-3.2-vision-90b", "mistral-7b",
-        "grok-1-314b", "deepseek-moe-16b", "nemotron-4-15b"]
+        "grok-1-314b", "deepseek-moe-16b", "nemotron-4-15b",
+        "deepseek-v2-lite"]
 
 
 def _nodrop(cfg):
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.capacity_factor is not None:
         # capacity token-dropping is seq-length dependent; disable for the
         # exactness check (the dropping path is tested in test_moe.py)
         return cfg.replace(moe=dataclasses.replace(cfg.moe,
