@@ -205,13 +205,37 @@ def set_flash_remat(on: bool) -> None:
     _FLASH_REMAT = on
 
 
+def _causal_self(Sq, Sk, causal, window_eff, q_offset) -> bool:
+    """Causal self-attention with a static zero window and offset: the
+    calls whose key blocks above the diagonal hold no visible score."""
+    def zero(x):
+        return isinstance(x, int) and x == 0
+    return causal and Sq == Sk and zero(window_eff) and zero(q_offset)
+
+
+def flash_blocks(Sq, Sk, chunk, *, causal=True, window_eff=0, q_offset=0):
+    """The (query block, key block) pairs ``sdpa_flash`` scores, in blocks
+    of ``min(chunk, Sk)`` rows, in the order it scores them.  Causal
+    self-attention with a static zero window and offset visits each query
+    block's diagonal block, then the key blocks below it: n(n+1)/2 of the
+    n² blocks.  Any other call scores every query against every key
+    block."""
+    C = min(chunk, Sk)
+    n = -(-Sk // C)
+    if _causal_self(Sq, Sk, causal, window_eff, q_offset):
+        return [(i, j) for i in range(n) for j in [i, *range(i)]]
+    return [(i, j) for j in range(n) for i in range(-(-Sq // C))]
+
+
 def sdpa_flash(q, k, v, *, causal=True, window_eff=0, chunk: int = 1024,
                q_offset=0, scale=None):
-    """XLA flash-structured attention: lax.scan over KV chunks with online
-    softmax — O(S·chunk) live scores instead of O(S²).  window_eff may be a
-    traced scalar (hymba per-layer global/sliding selection).  Values may be
-    narrower than queries and keys (MLA: 128 against 192); ``scale``
-    defaults to ``D ** -0.5`` of the query width."""
+    """XLA flash-structured attention: online softmax over KV chunks —
+    O(S·chunk) live scores instead of O(S²).  Causal self-attention scores
+    query blocks against the key blocks on and below the diagonal only
+    (``flash_blocks``); other calls scan all KV chunks with every query.
+    window_eff may be a traced scalar (hymba per-layer global/sliding
+    selection).  Values may be narrower than queries and keys (MLA: 128
+    against 192); ``scale`` defaults to ``D ** -0.5`` of the query width."""
     B, Sq, H, D = q.shape
     Dv = v.shape[-1]
     Sk, kvH = k.shape[1], k.shape[2]
@@ -219,11 +243,14 @@ def sdpa_flash(q, k, v, *, causal=True, window_eff=0, chunk: int = 1024,
     C = min(chunk, Sk)
     n = -(-Sk // C)
     pad = n * C - Sk
+    diagonal = _causal_self(Sq, Sk, causal, window_eff, q_offset)
     if pad:
         padw = ((0, 0), (0, pad), (0, 0), (0, 0))
         k, v = jnp.pad(k, padw), jnp.pad(v, padw)
+        if diagonal:
+            q = jnp.pad(q, padw)
     from repro.parallel.act import constrain
-    qg = q.reshape(B, Sq, kvH, G, D)
+    qg = q.reshape(B, q.shape[1], kvH, G, D)
     # keep attention sequence-sharded end to end (SP through the mixer):
     # scores stay (B,kvH,G,Sq/tp,C) local, KV chunks replicate over 'model'
     # (tiny) — avoids the partitioner's seq<->head all-to-all reshard.
@@ -232,40 +259,72 @@ def sdpa_flash(q, k, v, *, causal=True, window_eff=0, chunk: int = 1024,
     vc = v.reshape(B, n, C, kvH, Dv).transpose(1, 0, 2, 3, 4)
     kc = constrain(kc, None, "act_batch", None, None, None)
     vc = constrain(vc, None, "act_batch", None, None, None)
-    qi = (jnp.arange(Sq) + q_offset)[:, None]                 # (Sq, 1)
     scale = D ** -0.5 if scale is None else scale
 
-    def body(carry, inp):
-        m, l, acc = carry
-        j, kj, vj = inp
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kj,
+    def merge(carry, qb, kj, vj, valid):
+        """Online-softmax step of one key block; carry None starts it."""
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, kj,
                        preferred_element_type=jnp.float32) * scale
-        ki = j * C + jnp.arange(C)[None, :]                   # (1, C)
-        valid = ki < Sk
-        if causal:
-            valid &= ki <= qi
-        if not (isinstance(window_eff, int) and window_eff == 0):
-            w = window_eff
-            valid &= (w == 0) | (ki > qi - w)
-        s = jnp.where(valid[None, None, None], s, -1e30)
-        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        if valid is not None:
+            s = jnp.where(valid[None, None, None], s, -1e30)
+        m_blk = s.max(-1, keepdims=True)
+        m_new = m_blk if carry is None else jnp.maximum(carry[0], m_blk)
         p_ = jnp.exp(s - m_new)
+        pv = jnp.einsum("bhgqk,bkhd->bhgqd", p_.astype(vj.dtype), vj)
+        if carry is None:
+            return m_new, p_.sum(-1, keepdims=True), pv.astype(jnp.float32)
+        m, l, acc = carry
         alpha = jnp.exp(m - m_new)
-        l = alpha * l + p_.sum(-1, keepdims=True)
-        acc = acc * alpha + jnp.einsum(
-            "bhgqk,bkhd->bhgqd", p_.astype(vj.dtype), vj)
-        return (m_new, l, acc), None
+        return m_new, alpha * l + p_.sum(-1, keepdims=True), acc * alpha + pv
 
-    if _FLASH_REMAT:
-        body = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.nothing_saveable,
+    def remat(f):
+        if not _FLASH_REMAT:
+            return f
+        return jax.checkpoint(
+            f, policy=jax.checkpoint_policies.nothing_saveable,
             prevent_cse=False)
-    m0 = jnp.full((B, kvH, G, Sq, 1), -1e30, jnp.float32)
-    l0 = jnp.zeros((B, kvH, G, Sq, 1), jnp.float32)
-    a0 = jnp.zeros((B, kvH, G, Sq, Dv), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0), (jnp.arange(n), kc, vc))
-    out = acc / jnp.maximum(l, 1e-30)
+
+    if diagonal:
+        # query block i: the diagonal block (i, i), the only one with masked
+        # scores (and, in the last, the padded keys), then a scan over the
+        # key blocks below it, which every query of the block sees.
+        blocks = flash_blocks(Sq, Sk, chunk)
+        tri = jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]
+        step = remat(merge)
+        outs = []
+        for i in range(n):
+            row = [j for qb_, j in blocks if qb_ == i]
+            qb = constrain(qg[:, i * C:(i + 1) * C],
+                           "act_batch", "act_seq", None, None, None)
+            carry = step(None, qb, kc[row[0]], vc[row[0]], tri)
+            if row[1:]:
+                lo, hi = row[1], row[-1] + 1
+                carry, _ = jax.lax.scan(
+                    lambda c, kv, qb=qb: (step(c, qb, *kv, None), None),
+                    carry, (kc[lo:hi], vc[lo:hi]))
+            m, l, acc = carry
+            outs.append(acc / jnp.maximum(l, 1e-30))
+        out = jnp.concatenate(outs, axis=3)[:, :, :, :Sq]
+    else:
+        qi = (jnp.arange(Sq) + q_offset)[:, None]             # (Sq, 1)
+
+        def body(carry, inp):
+            j, kj, vj = inp
+            ki = j * C + jnp.arange(C)[None, :]               # (1, C)
+            valid = ki < Sk
+            if causal:
+                valid &= ki <= qi
+            if not (isinstance(window_eff, int) and window_eff == 0):
+                w = window_eff
+                valid &= (w == 0) | (ki > qi - w)
+            return merge(carry, qg, kj, vj, valid), None
+
+        m0 = jnp.full((B, kvH, G, Sq, 1), -1e30, jnp.float32)
+        l0 = jnp.zeros((B, kvH, G, Sq, 1), jnp.float32)
+        a0 = jnp.zeros((B, kvH, G, Sq, Dv), jnp.float32)
+        (m, l, acc), _ = jax.lax.scan(
+            remat(body), (m0, l0, a0), (jnp.arange(n), kc, vc))
+        out = acc / jnp.maximum(l, 1e-30)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dv).astype(q.dtype)
 
 
