@@ -246,7 +246,7 @@ def vector_speedup() -> List[Row]:
 
 
 def jax_speedup() -> List[Row]:
-    """End-to-end jitted fleet scan (`run_fleet_scan`, the engine="jax"
+    """End-to-end jitted fleet scan (`run_fleet_scan`, the compiled
     whole-run program behind Monte-Carlo sweeps) vs the vectorized numpy
     engine, stepping a 256-node fleet.
 
@@ -266,7 +266,7 @@ def jax_speedup() -> List[Row]:
         built.cluster.step()
     vector_s = time.perf_counter() - t0
     wl = sc.workload.build()
-    spec = fleet_scan_spec(wl, sc.sim, sc.fleet, reps, collect="summary")
+    spec = fleet_scan_spec(wl, sc.sim, sc.fleet, reps)
     warm = build_fleet_arrays(wl, sc.node.build_preset(), sc.sim,
                               sc.fleet, sc.node.caps_w, sc.seed)
     run_fleet_scan(spec, warm)              # compile once (cached program)

@@ -3,7 +3,7 @@ replay and engine-parity contracts.
 
 Every headline guarantee of this reproduction — bit-for-bit offline replay
 of cap schedules, drain decisions and alerts; float-identical traces across
-the event/batched/vector/jax engines; ``[seed, k]`` prefix-stable
+the event/batched/vector engines; ``[seed, k]`` prefix-stable
 Monte-Carlo populations — rests on coding invariants.  This package
 mechanizes them as lint rules so a violation is rejected before it can rot
 a guarantee the equivalence tests only catch after the fact:

@@ -3,9 +3,9 @@
 The repo ships two implementations of the same simulation contract: the
 numpy reference engine (``core/c3sim.py`` + ``core/cluster.py`` /
 ``core/topology.py``) and the jax engine (``core/jax_engine.py``).  The
-parity tests assert float-identical trajectories — but they can only
-catch drift in behavior they exercise.  This rule catches the *config*
-form of drift mechanically: a ``SimConfig`` / ``ClusterConfig`` /
+parity tests assert float-identical iterations and statistically equal
+runs — but they can only catch drift in behavior they exercise.  This
+rule catches the *config* form of drift mechanically: a ``SimConfig`` / ``ClusterConfig`` /
 ``Workload`` field that one engine side reads and the other silently
 ignores means a knob that changes one engine's output and not the
 other's.

@@ -62,7 +62,7 @@ def _add_scenario_args(p: argparse.ArgumentParser) -> None:
     p.add_argument("--iterations", type=int, default=None,
                    help="override the scenario's iteration count")
     p.add_argument("--seed", type=int, default=None)
-    p.add_argument("--engine", choices=["event", "batched", "vector", "jax"],
+    p.add_argument("--engine", choices=["event", "batched", "vector"],
                    help="override the simulation engine")
     p.add_argument("--set", action="append", metavar="KEY=VALUE",
                    help="dotted-path override, e.g. --set sim.noise=0.01")

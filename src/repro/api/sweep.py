@@ -281,7 +281,6 @@ def _run_batch_jax(variants: List[Scenario],
                 return None
             workloads.append(wl)
             specs.append(fleet_scan_spec(wl, sc.sim, sc.fleet, iterations,
-                                         collect="summary",
                                          devices_per_node=sc.node.devices))
         if len(set(specs)) != 1:
             return None                   # mixed shapes: no single program

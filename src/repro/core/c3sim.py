@@ -45,7 +45,6 @@ class SimConfig:
     seed: int = 0
     engine: str = "event"           # "event" (heap reference) | "batched"
     #                                 | "vector" (numpy, batches node groups)
-    #                                 | "jax" (XLA, jitted; see jax_engine)
 
 
 def workload_arrays(wl: Workload) -> dict:
@@ -153,10 +152,10 @@ class C3Sim:
 
         The engine entry point: ``engine`` (default ``cfg.engine``) picks
         the execution strategy — ``"event"`` (heap reference),
-        ``"batched"`` (per-window numpy), ``"vector"`` / ``"jax"``
-        (all-lanes batched; this sim becomes a single-group call).  All
-        engines consume the same RNG draws (`_draw_noise` runs first), so
-        the choice never changes the physics — see docs/engines.md for the
+        ``"batched"`` (per-window numpy) or ``"vector"`` (all-lanes
+        batched; this sim becomes a single-group call).  All engines
+        consume the same RNG draws (`_draw_noise` runs first), so the
+        choice never changes the physics — see docs/engines.md for the
         per-pair equivalence guarantees."""
         engine = engine or self.cfg.engine
         noise_c, dur_comm = self._draw_noise()
@@ -167,11 +166,8 @@ class C3Sim:
         if engine == "vector":
             return vector_iteration([self], [np.asarray(freq, float)],
                                     [(noise_c, dur_comm)])[0]
-        if engine == "jax":
-            from repro.core.jax_engine import jax_iteration
-            return jax_iteration([self], [np.asarray(freq, float)],
-                                 [(noise_c, dur_comm)])[0]
-        raise ValueError(f"unknown engine {engine!r}")
+        raise ValueError(f"unknown engine {engine!r}: accepted engines are "
+                         f"'event', 'batched' and 'vector'")
 
     # ----------------------------------------------------- event (reference)
     def _run_event(self, freq: np.ndarray, noise_c: np.ndarray,
@@ -690,14 +686,20 @@ class NodeSim:
         converges toward them on subsequent `commit` calls."""
         self.state.cap = np.asarray(caps, float).copy()
 
+    def iteration_freq(self) -> np.ndarray:
+        """The per-device frequencies this iteration runs at: the governed
+        ones, times any fault's ``perf_scale``.  Kept as ``_freq_used``
+        for `commit`'s history row."""
+        self._freq_used = self.state.freq.copy()
+        if self.perf_scale is not None:
+            self._freq_used = self._freq_used * self.perf_scale
+        return self._freq_used
+
     def run_only(self) -> IterationTrace:
         """Execute one iteration at current frequencies without committing
         physics — a cluster layer runs all nodes first, then commits with
         the global (barrier-stretched) interval."""
-        self._freq_used = self.state.freq.copy()
-        if self.perf_scale is not None:
-            self._freq_used = self._freq_used * self.perf_scale
-        return self.sim.run_iteration(self._freq_used)
+        return self.sim.run_iteration(self.iteration_freq())
 
     def commit(self, trace: IterationTrace,
                t_interval: Optional[float] = None,

@@ -50,8 +50,8 @@ class ClusterConfig:
     straggler_boost: float = 1.28     # r_th multiplier for that GPU
     healthy_boost: float = 1.0        # boost on every other node's worst slot
     engine: str = "batched"           # C3Sim engine for node iterations:
-    #                                   "batched" | "event" | "vector" | "jax"
-    #                                   (vector/jax batch all nodes per step)
+    #                                   "batched" | "event" | "vector"
+    #                                   (vector batches all nodes per step)
     # ---------------------------------------------------------- topology
     topology: str = "dp"              # dp | pp | tp (see topology.py)
     microbatches: int = 8             # PP: microbatches per iteration
@@ -86,8 +86,7 @@ class ClusterSim:
         self.G = devices_per_node
         self.presets: List[DevicePreset] = self._resolve_presets(preset)
         self.preset = self.presets[0]
-        node_engine = ("batched" if cc.engine in ("vector", "jax")
-                       else cc.engine)
+        node_engine = "batched" if cc.engine == "vector" else cc.engine
         node_sim_cfg = dataclasses.replace(sim_cfg, engine=node_engine)
         churn = cc.churn or {}
         self.nodes: List[NodeSim] = []
@@ -157,21 +156,11 @@ class ClusterSim:
         return self.nodes[node].state.cap.copy()
 
     def _run_nodes(self) -> List[IterationTrace]:
-        if self.cfg.engine in ("vector", "jax") and self.N > 1:
-            # one batched pass over all N*G lanes (numpy or XLA); per-node
-            # RNG streams are drawn exactly as a per-node run would
-            freqs, noises = [], []
-            for node in self.nodes:
-                f = node.state.freq.copy()
-                if node.perf_scale is not None:
-                    f = f * node.perf_scale
-                node._freq_used = f
-                freqs.append(node._freq_used)
-                noises.append(node.sim._draw_noise())
-            if self.cfg.engine == "jax":
-                from repro.core.jax_engine import jax_iteration
-                return jax_iteration([n.sim for n in self.nodes],
-                                     freqs, noises)
+        if self.cfg.engine == "vector" and self.N > 1:
+            # one numpy pass over all N*G lanes; per-node RNG streams are
+            # drawn exactly as a per-node run would
+            freqs = [node.iteration_freq() for node in self.nodes]
+            noises = [node.sim._draw_noise() for node in self.nodes]
             return vector_iteration([n.sim for n in self.nodes],
                                     freqs, noises)
         return [node.run_only() for node in self.nodes]

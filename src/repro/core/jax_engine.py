@@ -1,60 +1,48 @@
 """Accelerator-native engine: the kernel-window arithmetic on JAX/XLA.
 
-Two programs live here, both jitted end-to-end:
-
-  * :func:`jax_iteration` — the per-iteration engine behind
-    ``engine="jax"``.  It is the drop-in analogue of
-    :func:`repro.core.c3sim.vector_iteration`: one iteration for B barrier
-    groups of G lanes each, computed as a single XLA program (``jax.vmap``
-    over groups, the per-window device loop unrolled over a *static window
-    plan* derived from the workload).  It consumes the **same numpy noise
-    draws** as the vector engine (``C3Sim._draw_noise``), so its traces are
-    the event/batched/vector traces up to float associativity (the
-    property tests in tests/test_jax_engine.py pin the tolerance and the
-    exact structural subset: NaN patterns, argmin/argmax outcomes, kernel
-    ordering).
-
-  * :func:`run_fleet_scan` — the whole-run engine behind Monte-Carlo
-    sweeps (``repro.api.sweep``).  The iteration/churn loop — kernel
-    windows, parallelism topology, thermal RC + DVFS governor, cooling
-    churn — runs inside one ``jax.lax.scan``, so a 1000-node fleet steps
-    T iterations (plus the NodeSim-style 30-iteration thermal warmup) in a
-    single device program, and a sweep vmaps that program over samples.
-    Per-kernel noise and TP jitter are drawn from JAX PRNG streams inside
-    the scan (numpy Generator streams cannot be replayed there), so this
-    path is *statistically* equivalent to ClusterSim, not trace-identical;
-    the static thermal lottery (per-device ``r_th`` / ``m_coef``) is
-    passed in as arrays and reproduces ClusterSim's numpy draws exactly
-    (see :func:`build_fleet_arrays`).
+One program lives here, jitted end-to-end: :func:`run_fleet_scan`, the
+whole-run engine behind Monte-Carlo sweeps (``repro.api.sweep``).  The
+iteration/churn loop — kernel windows, parallelism topology, thermal RC +
+DVFS governor, cooling churn — runs inside one ``jax.lax.scan``, so a
+1000-node fleet steps T iterations (plus the NodeSim-style 30-iteration
+thermal warmup) in a single device program, and a sweep vmaps that program
+over samples.  Per-kernel noise and TP jitter are drawn from JAX PRNG
+streams inside the scan (numpy Generator streams cannot be replayed there),
+so this path is *statistically* equivalent to ClusterSim, not
+trace-identical; the static thermal lottery (per-device ``r_th`` /
+``m_coef``) is passed in as arrays and reproduces ClusterSim's numpy draws
+exactly (see :func:`build_fleet_arrays`).  Each iteration's kernel windows
+(:func:`_group_summary`) are the vector engine's arithmetic: fed the same
+noise draws, they give its ``t_iter`` and ``util`` to float associativity
+(tests/test_jax_engine.py).
 
 Everything computes in float64 (``jax.enable_x64(True)`` is entered around
 tracing and execution; the global JAX config is left untouched so the
 float32 Pallas training substrate is unaffected) with one exception: the
-fleet scan's lognormal noise factors (per-kernel, per-collective and TP
-jitter) evaluate the inverse error function and the ``exp`` after it in
-float32 (:func:`_normal_f32`).  A TPU has no native float64; emulated,
-that ``erf_inv`` over every (sample, node, device, kernel) element was the
+lognormal noise factors (per-kernel, per-collective and TP jitter) evaluate
+the inverse error function and the ``exp`` after it in float32
+(:func:`_normal_f32`).  A TPU has no native float64; emulated, that
+``erf_inv`` over every (sample, node, device, kernel) element was the
 largest cost of a sweep, and the factors need no more than float32.  Their
 uniforms are still the float64 threefry draws of
 ``jax.random.normal(key, shape, float64)``, so the stream is the same and
 only the rounding differs: ≤ ~3e-7 of max(1, |z|) on the CPU, ≤ ~2.4e-5 on
-a TPU v5e, whose float32 ``log`` and ``exp`` are looser.  The same
-programs run on the CPU backend (tests, CI) and on a TPU, where float64 is
+a TPU v5e, whose float32 ``log`` and ``exp`` are looser.  The same program
+runs on the CPU backend (tests, CI) and on a TPU, where float64 is
 emulated.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["WindowPlan", "window_plan", "jax_iteration",
-           "FleetScanSpec", "fleet_scan_spec", "build_fleet_arrays",
-           "run_fleet_scan"]
+__all__ = ["WindowPlan", "window_plan", "FleetScanSpec", "fleet_scan_spec",
+           "build_fleet_arrays", "run_fleet_scan"]
 
 
 # --------------------------------------------------------------------------- #
@@ -191,7 +179,7 @@ def _step_table(plan: WindowPlan):
 
 
 def _iteration_scan(plan: WindowPlan, kappa_comp, kappa_mem,
-                    rate_f, rm, work_f, work_b, dur_comm, emit: bool):
+                    rate_f, rm, work_f, work_b, dur_comm):
     """Run the step-table scan for one barrier group of G lanes.
 
     Pure function of the per-lane compute rates (G,), the group memory
@@ -201,20 +189,15 @@ def _iteration_scan(plan: WindowPlan, kappa_comp, kappa_mem,
     workload's static `_step_table` so compile time is independent of
     kernel count; see `WindowPlan` for why no gate checks appear here.
 
-    The scan carries only (G,) lane state — trace matrices are *emitted*
-    per step (``emit=True``) and reassembled afterwards with static
-    segment reductions (carrying (Kc, G) buffers through a scan forces XLA
-    to copy them every step).  Two further identities keep the carry
-    small: at a comm-resolve step every lane's clock *is* its arrival
-    (producers just finished at ``t``, everyone else sits at the barrier),
-    and completion bookkeeping only needs the in-flight kernel's start
-    time (``cur_start``).  With ``emit=False`` only the carry survives —
-    enough for ``t_iter``/``util``, and several times cheaper; the fleet
-    scan runs in that mode.
+    The scan carries only (G,) lane state and emits nothing: that is
+    enough for ``t_iter``/``util``.  Two identities keep the carry small:
+    at a comm-resolve step every lane's clock *is* its arrival (producers
+    just finished at ``t``, everyone else sits at the barrier), and
+    completion bookkeeping only needs the in-flight kernel's start time
+    (``cur_start``).
 
-    Returns ``(carry, ys)`` where carry is
-    ``(t, ci, started, gfr, gbr, busy, cur_start, prev_end)`` and ys is
-    ``(s_rows, e_rows, o_rows)`` stacked over steps, or ``None``.
+    Returns the final carry ``(t, ci, started, gfr, gbr, busy, cur_start,
+    prev_end)``.
     """
     G = work_f.shape[0]
     Kc, Km = plan.n_comp, plan.n_comm
@@ -240,9 +223,6 @@ def _iteration_scan(plan: WindowPlan, kappa_comp, kappa_mem,
         rmm = jnp.where(cap, rm_s, rm)
         m = is_k & (ci == i)
         ns = m & ~started
-        # comm steps borrow the start-row slot for their arrivals (each
-        # lane's clock *is* its arrival); segment routing separates them
-        s_row = jnp.where(ns | is_c, t, INF) if emit else None
         cur_start = jnp.where(ns, t, cur_start)
         gfr = jnp.where(ns, w_f[jnp.minimum(i, Kc - 1)], gfr)
         gbr = jnp.where(ns, w_b[jnp.minimum(i, Kc - 1)], gbr)
@@ -250,7 +230,6 @@ def _iteration_scan(plan: WindowPlan, kappa_comp, kappa_mem,
         dt = gfr / rf + gbr / rmm
         fits = m & (t + dt <= ts)
         t = jnp.where(fits, t + dt, t)
-        e_row = jnp.where(fits, t, INF) if emit else None
         busy = busy + jnp.where(fits, t - cur_start, 0.0)
         started = started & ~fits
         ci = jnp.where(fits, i + 1, ci)
@@ -260,8 +239,6 @@ def _iteration_scan(plan: WindowPlan, kappa_comp, kappa_mem,
         gfr_new = jnp.where(pp, gfr - use * rate_f_s, gfr)
         gbr = jnp.where(pp, jnp.maximum(0.0, gbr - (avail - use) * rm_s),
                         gbr)
-        o_row = (jnp.where(fits & cap, dt, 0.0)
-                 + jnp.where(pp, avail, 0.0)) if emit else None
         # -- comm resolve: the collective globally ends at max arrival
         #    (= max lane clock) + duration, which is the next barrier
         ge = jnp.max(t) + dur[jnp.minimum(i, max(Km, 1) - 1)]
@@ -269,41 +246,13 @@ def _iteration_scan(plan: WindowPlan, kappa_comp, kappa_mem,
         # -- barrier pull: window over, every lane ends at the barrier
         t = jnp.where(kind == _K_PULL, prev_end, t)
         new = (t, ci, started, gfr_new, gbr, busy, cur_start, prev_end)
-        return new, ((s_row, e_row, o_row) if emit else None)
+        return new, None
 
     init = (jnp.zeros((G,)), jnp.zeros((G,), jnp.int32),
             jnp.zeros((G,), bool), jnp.zeros((G,)), jnp.zeros((G,)),
             jnp.zeros((G,)), jnp.zeros((G,)), jnp.asarray(0.0))
-    return jax.lax.scan(body, init, xs)
-
-
-def _group_iteration(plan: WindowPlan, kappa_comp, kappa_mem,
-                     rate_f, rm, work_f, work_b, dur_comm):
-    """One full-trace iteration for one group: scan + trace reassembly."""
-    Kc, Km = plan.n_comp, plan.n_comm
-    kinds_np, idx_np, _ = _step_table(plan)
-    carry, ys = _iteration_scan(plan, kappa_comp, kappa_mem, rate_f, rm,
-                                work_f, work_b, dur_comm, emit=True)
-    busy = carry[5]
-    s_rows, e_rows, o_rows = ys
-
-    # reassemble (G, Kc)/(G, Km) trace matrices via static routing tables:
-    # each (lane, kernel) start/end is written at most once (INF elsewhere),
-    # so segment-min over the step axis recovers it; overlaps accumulate.
-    # comm steps route to the dump segment Kc so their borrowed start-row
-    # values never reach the compute matrices.
-    seg = jnp.asarray(np.where(kinds_np == _K_KERNEL, idx_np, Kc))
-    comp_start = jax.ops.segment_min(s_rows, seg, num_segments=Kc + 1)[:Kc]
-    comp_end = jax.ops.segment_min(e_rows, seg, num_segments=Kc + 1)[:Kc]
-    comp_ovl = jax.ops.segment_sum(o_rows, seg, num_segments=Kc + 1)[:Kc]
-    comp_start = jnp.where(jnp.isinf(comp_start), jnp.nan, comp_start)
-    comp_end = jnp.where(jnp.isinf(comp_end), jnp.nan, comp_end)
-    comm_pos = jnp.asarray(np.flatnonzero(kinds_np == _K_COMM))
-    comm_lstart = s_rows[comm_pos]               # (Km, G), one row per comm
-    comm_gend = jnp.max(comm_lstart, axis=1) + dur_comm[:Km]
-    return (jnp.transpose(comp_start), jnp.transpose(comp_end),
-            jnp.transpose(comp_ovl), jnp.transpose(comm_lstart),
-            comm_gend, busy)
+    carry, _ = jax.lax.scan(body, init, xs)
+    return carry
 
 
 def _group_summary(plan: WindowPlan, kappa_comp, kappa_mem,
@@ -313,70 +262,14 @@ def _group_summary(plan: WindowPlan, kappa_comp, kappa_mem,
     ``t_iter`` is the max lane clock after the drain (per-lane completion
     times are nondecreasing, so the final clock is the lane's last
     completion) held to the final barrier; ``util`` is busy time over it.
-    Several times cheaper than `_group_iteration` — no per-step trace
-    emission — and what `run_fleet_scan` iterates.
+    What `run_fleet_scan` iterates.
     """
-    carry, _ = _iteration_scan(plan, kappa_comp, kappa_mem, rate_f, rm,
-                               work_f, work_b, dur_comm, emit=False)
+    carry = _iteration_scan(plan, kappa_comp, kappa_mem, rate_f, rm,
+                            work_f, work_b, dur_comm)
     t, busy, prev_end = carry[0], carry[5], carry[7]
     t_iter = jnp.maximum(jnp.max(t), prev_end)
     util = busy / jnp.maximum(t_iter, 1e-12)
     return t_iter, util
-
-
-@functools.lru_cache(maxsize=32)
-def _compiled_iteration(plan: WindowPlan, kappa_comp: float,
-                        kappa_mem: float):
-    """Jitted vmap of `_group_iteration` over B groups, cached per
-    (workload plan, contention factors)."""
-    fn = functools.partial(_group_iteration, plan, kappa_comp, kappa_mem)
-    return jax.jit(jax.vmap(fn))
-
-
-def jax_iteration(sims: Sequence, freqs: Sequence[np.ndarray],
-                  noises: Sequence[tuple]) -> List:
-    """Run one iteration for B node-groups as a single XLA program.
-
-    Same contract as :func:`repro.core.c3sim.vector_iteration`: every sim
-    must share one Workload, presets/frequencies may differ per group,
-    comm barriers are per group, and ``noises`` carries each sim's own
-    ``_draw_noise()`` output so per-node numpy RNG streams stay identical
-    to a per-node run.  Returns the per-group `IterationTrace`s; they
-    match the vector engine's within float tolerance (XLA may fuse
-    multiply-adds, so bitwise equality is not guaranteed).
-    """
-    wl = sims[0].wl
-    A = sims[0].arrays
-    cfg = sims[0].cfg
-    for s in sims[1:]:
-        if s.arrays is not A:
-            raise ValueError("jax_iteration: all sims must share one "
-                             "Workload (kernel schedules must be identical)")
-    plan = window_plan(wl)
-    B, G = len(sims), sims[0].G
-
-    rate_f = np.empty((B, G))
-    rm = np.empty(B)
-    for b, (s, f) in enumerate(zip(sims, freqs)):
-        p = s.preset
-        rate_f[b] = p.peak_gflops * cfg.gemm_eff * (np.asarray(f) / p.f_max)
-        rm[b] = p.hbm_gbps
-    noise_c = np.stack([n for n, _ in noises])       # (B, G, Kc)
-    dur_comm = np.stack([d for _, d in noises])      # (B, Km)
-    work_f = A["gflop"][None, None, :] * noise_c
-    work_b = A["gbyte"][None, None, :] * noise_c
-
-    fn = _compiled_iteration(plan, float(cfg.kappa_comp),
-                             float(cfg.kappa_mem))
-    with jax.enable_x64(True):
-        out = fn(jnp.asarray(rate_f), jnp.asarray(rm),
-                 jnp.asarray(work_f), jnp.asarray(work_b),
-                 jnp.asarray(dur_comm))
-    comp_start, comp_end, comp_ovl, comm_lstart, comm_gend, busy = (
-        np.asarray(x) for x in out)
-    return [sims[b]._make_trace(comp_start[b], comp_end[b], comp_ovl[b],
-                                comm_lstart[b], comm_gend[b], busy[b])
-            for b in range(B)]
 
 
 # --------------------------------------------------------------------------- #
@@ -400,7 +293,6 @@ class FleetScanSpec:
     microbatches: int = 8               # pp
     tp_syncs: int = 16                  # tp
     spike: bool = False                 # comm latency spikes enabled
-    collect: str = "full"               # "full": (T, N) series | "summary"
 
 
 _NODE_FIELDS = ("f_max", "f_min", "p_idle", "peak_gflops", "hbm_gbps",
@@ -409,7 +301,6 @@ _NODE_FIELDS = ("f_max", "f_min", "p_idle", "peak_gflops", "hbm_gbps",
 
 
 def fleet_scan_spec(workload, sim_cfg, cluster_cfg, iterations: int,
-                    collect: str = "full",
                     devices_per_node: int = 8) -> FleetScanSpec:
     """The static companion of `build_fleet_arrays` for one scenario."""
     from repro.core.topology import make_topology
@@ -422,7 +313,7 @@ def fleet_scan_spec(workload, sim_cfg, cluster_cfg, iterations: int,
         n_devices=devices_per_node, iterations=int(iterations),
         topology=cc.topology, microbatches=cc.microbatches,
         tp_syncs=int(getattr(topo, "K", 1)),
-        spike=bool(sim_cfg.comm_spike_p > 0), collect=collect)
+        spike=bool(sim_cfg.comm_spike_p > 0))
 
 
 def build_fleet_arrays(workload, preset, sim_cfg, cluster_cfg,
@@ -696,24 +587,16 @@ def _fleet_scan_core(spec: FleetScanSpec, a: Dict):
         temp, freq, power, t_sim = commit(temp, freq, a["caps"], t_sim,
                                           util_eff, jnp.full(N, t_fleet))
         node_power = jnp.sum(power, axis=1)
-        if spec.collect == "full":
-            out = (t_fleet, t_local, lead, node_power)
-        else:
-            out = (t_fleet, jnp.max(lead), jnp.argmax(t_local),
-                   jnp.argmin(lead), jnp.sum(node_power))
+        out = (t_fleet, jnp.max(lead), jnp.argmax(t_local),
+               jnp.argmin(lead), jnp.sum(node_power))
         return (temp, freq, t_sim), out
 
-    (temp, freq, t_sim), series = jax.lax.scan(
+    (temp, freq, _), series = jax.lax.scan(
         main_body, (temp, freq, t_sim), jnp.arange(spec.iterations))
-    state = {"temp": temp, "freq": freq}
-    if spec.collect == "full":
-        t_fleet, t_local, lead, node_power = series
-        return {"t_fleet": t_fleet, "t_local": t_local, "lead": lead,
-                "node_power": node_power, **state}
     t_fleet, lead_max, slowest, strag, power = series
     return {"t_fleet": t_fleet, "lead_max": lead_max,
             "slowest_node": slowest, "straggler_node": strag,
-            "fleet_power": power, **state}
+            "fleet_power": power, "temp": temp, "freq": freq}
 
 
 @functools.lru_cache(maxsize=16)
@@ -727,10 +610,10 @@ def run_fleet_scan(spec: FleetScanSpec,
     """Execute one fleet run — or, when every array carries a leading
     sample axis, a whole batch of runs — as a single jitted scan program.
 
-    Returns per-iteration series (``t_fleet`` plus, per ``spec.collect``,
-    either full (T, N) ``t_local``/``lead``/``node_power`` series or
-    per-iteration summary scalars) and the final thermal ``temp``/``freq``
-    state, as numpy arrays.
+    Returns per-iteration fleet series — ``t_fleet``, ``lead_max``,
+    ``slowest_node`` (argmax of node iteration time), ``straggler_node``
+    (argmin of lead) and ``fleet_power`` — and the final thermal
+    ``temp``/``freq`` state, as numpy arrays.
 
     Three profiler spans name its host stages: ``fleet_scan.put`` (inputs
     to the device), ``fleet_scan.call`` (dispatch, and compile on a cache

@@ -1,18 +1,20 @@
-"""engine="jax": the XLA port of the C3 window arithmetic, plus the
-whole-run fleet scan behind Monte-Carlo sweeps.
+"""The compiled fleet scan behind Monte-Carlo sweeps: the XLA port of the
+C3 window arithmetic inside one whole-run program.
 
 Two equivalence tiers (docs/engines.md):
 
-  * ``jax_iteration`` consumes the *same numpy noise stream* as the vector
-    engine, so per-iteration traces line up float-for-float (tolerance for
-    accumulation order) — property-tested across topologies, heterogeneous
-    presets, and churn.
+  * the scan's per-group iteration (``_group_summary``), fed one C3Sim's
+    own ``_draw_noise()`` output, gives the vector engine's ``t_iter`` and
+    ``util`` float-for-float (tolerance for accumulation order) — across
+    dense, spiking and MoE-blocking workloads and a frequency spread down
+    to a preset's floor.
   * ``run_fleet_scan`` keeps the whole warmup/churn/iteration loop inside
     one jitted scan with jax-PRNG noise: identical thermal lotteries and
     physics, a different noise stream — so the check is statistical
     (tail-mean fleet metrics), driven through the sweep module against its
     own per-sample ``ClusterSim`` path (``_run_one_python``).
 """
+import functools
 import json
 
 import numpy as np
@@ -20,85 +22,89 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from conftest import small_workload
-from repro.core.c3sim import SimConfig
-from repro.core.cluster import ClusterConfig, ClusterSim
+from repro.core.c3sim import C3Sim, SimConfig, vector_iteration
 from repro.core.jax_engine import window_plan
-from repro.core.thermal import MI300X_PRESET, ChurnEvent, ChurnModel
-
-HETERO = ["mi300x", "mi300x-air", "mi300x", "v5e"]
+from repro.core.thermal import MI300X_PRESET, V5E_PRESET
 
 
-def _cluster(engine, topo="dp", seed=5, hetero=False, churn=False,
-             noise=None):
-    kw = {}
-    if hetero:
-        kw["node_presets"] = HETERO
-    if churn:
-        # fresh ChurnModel per sim — the model is stateless but keep the
-        # two engines' configs independent anyway
-        kw["churn"] = {0: ChurnModel(events=[ChurnEvent(0.0, 3, 1.4)])}
-    sim_kw = dict(seed=1, comm_gbps=40.0)
-    if noise is not None:
-        sim_kw["noise"] = noise
-    return ClusterSim(small_workload(n_layers=8), MI300X_PRESET,
-                      SimConfig(**sim_kw),
-                      ClusterConfig(n_nodes=4, straggler_boost=1.28,
-                                    topology=topo, engine=engine, **kw),
-                      devices_per_node=8, seed=seed)
+def _moe_workload():
+    from repro.configs import get_config
+    from repro.core.workload import fsdp_llm_iteration
+    cfg = get_config("deepseek-v3-16b").replace(n_layers=4)
+    return fsdp_llm_iteration(cfg, batch=2, seq=4096, n_shards=8)
 
 
-def _assert_traces_close(ta, tb):
-    for field in ("comp_start", "comp_end", "comp_overlap",
-                  "comm_start", "comm_end", "util"):
-        a, b = getattr(ta, field), getattr(tb, field)
-        np.testing.assert_array_equal(np.isnan(a), np.isnan(b),
-                                      err_msg=f"{field}: NaN pattern")
-        np.testing.assert_allclose(a, b, rtol=1e-9, atol=1e-12,
-                                   equal_nan=True, err_msg=field)
-    assert ta.t_iter == pytest.approx(tb.t_iter, rel=1e-9)
+# case -> (workload, preset, lowest frequency of the spread, SimConfig kw)
+_CASES = {
+    "dense": (lambda: small_workload(n_layers=4), MI300X_PRESET, 1.4, {}),
+    "dense-spike": (lambda: small_workload(n_layers=8), MI300X_PRESET, 1.4,
+                    {"comm_spike_p": 0.05}),
+    "moe-blocking": (_moe_workload, MI300X_PRESET, 1.4, {}),
+    "hetero-preset": (lambda: small_workload(n_layers=4), V5E_PRESET,
+                      V5E_PRESET.f_min, {}),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_summary(plan):
+    """One compiled `_group_summary` per window plan (contention factors
+    ride as arguments, as in the fleet scan)."""
+    import jax
+    from repro.core.jax_engine import _group_summary
+    return jax.jit(functools.partial(_group_summary, plan))
+
+
+def _scan_vs_vector(wl, preset, freq, **sim_kw):
+    """Run one iteration of one C3Sim's noise draw through the vector
+    engine and through the scan's `_group_summary`; return both
+    ``(t_iter, util)`` pairs."""
+    import jax
+    cfg = SimConfig(comm_gbps=40.0, **sim_kw)
+    sim = C3Sim(wl, preset, cfg, 8)
+    noise_c, dur_comm = sim._draw_noise()
+    tv = vector_iteration([sim], [freq], [(noise_c, dur_comm)])[0]
+    A = sim.arrays
+    rate_f = preset.peak_gflops * cfg.gemm_eff * (freq / preset.f_max)
+    with jax.enable_x64(True):
+        t_iter, util = _jitted_summary(window_plan(wl))(
+            cfg.kappa_comp, cfg.kappa_mem, rate_f, preset.hbm_gbps,
+            A["gflop"][None, :] * noise_c, A["gbyte"][None, :] * noise_c,
+            dur_comm)
+    return (tv.t_iter, tv.util), (float(t_iter), np.asarray(util))
+
+
+def _assert_scan_matches(vector, scan):
+    (tv, uv), (ts, us) = vector, scan
+    assert ts == pytest.approx(tv, rel=1e-9)
+    np.testing.assert_allclose(us, uv, rtol=1e-9, atol=1e-12)
 
 
 # --------------------------------------------------------------------------- #
-# per-iteration equivalence: jax vs vector
+# per-iteration equivalence: the scan's window arithmetic vs vector
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("topo", ["dp", "pp", "tp"])
-def test_cluster_jax_engine_matches_vector(topo):
-    """engine='jax' steps all N*G lanes as one XLA program and must emit
-    the vector engine's traces (same RNG stream, float tolerance only for
-    accumulation order) — the cluster layer on top cannot tell them
-    apart."""
-    cv, cj = _cluster("vector", topo), _cluster("jax", topo)
-    for _ in range(3):
-        tv, tj = cv.step(), cj.step()
-        for a, b in zip(tv, tj):
-            _assert_traces_close(a, b)
-    assert cv.history[-1]["t_fleet"] == pytest.approx(
-        cj.history[-1]["t_fleet"], rel=1e-9)
-    np.testing.assert_allclose(cv.history[-1]["lead"],
-                               cj.history[-1]["lead"],
-                               rtol=1e-6, atol=1e-12)
+@pytest.mark.parametrize("case", list(_CASES))
+def test_scan_iteration_matches_vector(case):
+    """The fleet scan's per-group iteration is the vector engine's
+    arithmetic: fed the same noise draw, it gives the same ``t_iter`` and
+    per-device ``util``."""
+    make_wl, preset, freq_lo, sim_kw = _CASES[case]
+    wl = make_wl()
+    freq = np.linspace(freq_lo, preset.f_max, 8)
+    for seed in range(6):
+        _assert_scan_matches(*_scan_vs_vector(wl, preset, freq, seed=seed,
+                                              **sim_kw))
 
 
 @settings(deadline=None, max_examples=12)
-@given(seed=st.integers(0, 2 ** 16),
-       topo=st.sampled_from(["dp", "pp", "tp"]),
-       hetero=st.booleans(), churn=st.booleans())
-def test_jax_engine_matches_vector_property(seed, topo, hetero, churn):
-    """Property: for any thermal-lottery seed, topology, fleet mix, and
-    churn setting, the jax engine's iteration is the vector engine's."""
-    cv = _cluster("vector", topo, seed=seed, hetero=hetero, churn=churn)
-    cj = _cluster("jax", topo, seed=seed, hetero=hetero, churn=churn)
-    for _ in range(2):
-        tv, tj = cv.step(), cj.step()
-    for a, b in zip(tv, tj):
-        np.testing.assert_array_equal(np.isnan(a.comp_end),
-                                      np.isnan(b.comp_end))
-        np.testing.assert_allclose(a.comp_end, b.comp_end,
-                                   rtol=1e-9, atol=1e-12, equal_nan=True)
-        np.testing.assert_allclose(a.comm_end, b.comm_end,
-                                   rtol=1e-9, atol=1e-12, equal_nan=True)
-    assert cv.history[-1]["t_fleet"] == pytest.approx(
-        cj.history[-1]["t_fleet"], rel=1e-9)
+@given(seed=st.integers(0, 2 ** 16), freq_lo=st.floats(1.0, 2.05),
+       spike_p=st.sampled_from([0.0, 0.05]))
+def test_scan_iteration_matches_vector_property(seed, freq_lo, spike_p):
+    """Property: for any seed, frequency spread and spike setting, the
+    scan's iteration is the vector engine's."""
+    freq = np.linspace(freq_lo, 2.1, 8)
+    _assert_scan_matches(*_scan_vs_vector(
+        small_workload(n_layers=4), MI300X_PRESET, freq, seed=seed,
+        comm_spike_p=spike_p))
 
 
 def test_window_plan_caches_on_workload():
@@ -167,7 +173,7 @@ def test_fleet_scan_has_no_float64_erf_inv(scenario):
 
     sc = get_scenario(scenario).replace(manager=None)
     wl = sc.workload.build()
-    spec = fleet_scan_spec(wl, sc.sim, sc.fleet, 3, collect="summary",
+    spec = fleet_scan_spec(wl, sc.sim, sc.fleet, 3,
                            devices_per_node=sc.node.devices)
     assert spec.n_nodes == 4
     rows = [build_fleet_arrays(wl, sc.node.build_preset(), sc.sim, sc.fleet,

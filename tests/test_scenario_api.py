@@ -323,7 +323,7 @@ def _wl8():
     return fsdp_llm_iteration(cfg, batch=2, seq=4096, n_shards=8)
 
 
-@pytest.mark.parametrize("engine", ["event", "batched", "vector", "jax"])
+@pytest.mark.parametrize("engine", ["event", "batched", "vector"])
 def test_cluster_dp_scenario_matches_hand_wired_bit_for_bit(engine):
     """`run_scenario` on ``cluster/dp`` == the pre-API ClusterSim +
     FleetPowerManager composition, float for float, per engine."""
@@ -367,6 +367,24 @@ def test_cluster_dp_scenario_matches_hand_wired_bit_for_bit(engine):
     # the managed loop must actually have adjusted something, or the
     # equality above is vacuous
     assert len(mgr.budget_log) > 0
+
+
+@pytest.mark.parametrize("scope,name", [("sim", "paper/node-cap"),
+                                        ("fleet", "cluster/dp")])
+def test_retired_jax_engine_is_rejected(scope, name, tmp_path, capsys):
+    """The per-iteration jax engine is gone: a scenario that still names
+    it fails loudly when it is built, naming the engines that remain, and
+    the CLI's engine flag refuses it as a usage error."""
+    sc = with_overrides(get_scenario(name), {f"{scope}.engine": "jax"})
+    with pytest.raises(ValueError, match="vector"):
+        build_scenario(sc)
+    p = str(tmp_path / "jax.json")
+    sc.save(p)
+    assert cli_main(["run", "--spec", p, "--iterations", "2"]) == 2
+    assert "vector" in capsys.readouterr().err
+    with pytest.raises(SystemExit) as exc:
+        cli_main(["run", name, "--engine", "jax"])
+    assert exc.value.code == 2
 
 
 def test_node_manager_scenario_matches_hand_wired_bit_for_bit():
